@@ -6,7 +6,9 @@
 ``--list`` prints the available suite names (for shell completion and CI
 matrix generation) and exits 0.  ``--only`` selects suites so a CI job only pays for what it checks
 (unknown names fail fast with exit code 2 — a typo must not silently
-skip a gate).  Prints ``name,us_per_call,derived`` CSV rows per the
+skip a gate).  Compiles go to the persistent cache
+``repro.compile_cache`` places (``JAX_COMPILATION_CACHE_DIR`` or
+``<checkout>/.jax_cache``).  Prints ``name,us_per_call,derived`` CSV rows per the
 harness contract.  Wall times are CPU-container measurements of the
 jitted JAX paths; the eFPGA-model columns (cycles/latency/energy) are
 derived from the paper's published pipeline/frequency constants (see
@@ -61,6 +63,9 @@ def main() -> int:
         )
         return 2
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name in wanted:
         mod = importlib.import_module(f".{SUITES[name]}", __package__)

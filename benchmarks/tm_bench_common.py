@@ -56,10 +56,10 @@ def trained_tm(dataset: str, n_clauses: int = 60, epochs: int = 8) -> TrainedTM:
     return TrainedTM(dataset, cfg, state, model, acc, xt, yt)
 
 
-def synthetic_mnist_scale() -> tuple[TMConfig, CompressedModel]:
+def synthetic_mnist_scale(seed: int = 0) -> tuple[TMConfig, CompressedModel]:
     """Paper's MNIST numbers: 10 classes x 200 clauses x 1568 literals,
-    ~17k includes (0.54% density)."""
-    rng = np.random.default_rng(0)
+    ~17k includes (0.54% density); random includes drawn from ``seed``."""
+    rng = np.random.default_rng(seed)
     cfg = TMConfig(n_classes=10, n_clauses=200, n_features=784)
     acts = rng.random((10, 200, 1568)) < 17000 / 3136000
     return cfg, encode(cfg, acts)

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core import TMConfig
 from repro.data.pipeline import TMDatasetSpec, booleanized_tm_dataset
+from repro.dist.sharding import make_mesh
 from repro.recal import (
     DriftMonitor,
     RecalController,
@@ -79,7 +80,7 @@ def _bench_train_engines(cfg, state0, x, y, batch: int, steps: int) -> dict:
     xb = jnp.asarray(np.asarray(x[:batch], np.uint8))
     yb = jnp.asarray(np.asarray(y[:batch], np.int32))
     key = jax.random.key(0x7E57)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     engines = {
         "reference": make_train_engine("reference", cfg),
         "packed": make_train_engine("packed", cfg),

@@ -46,15 +46,20 @@ from ..configs.base import _pad_to
 from ..core.compress import CompressedModel, decode_to_plan
 from ..core.interp import interpret_stream, pack_features, pad_plan, plan_class_sums
 from ..core.tm import literals, pack_literals
-from ..dist.sharding import _axis_sizes
+from ..dist.sharding import _axis_sizes, make_mesh
 from ..dist.tm_sharded import (
     TMShardedConfig,
     build_tm_sharded,
     fill_clause_tables,
 )
-from ..kernels.tm_popcount.kernel import tm_popcount, tm_popcount_xla
+from ..kernels.tm_popcount.kernel import (
+    kernel_blocks,
+    kernel_operands,
+    sum_weight_planes,
+    tm_popcount_resident,
+    tm_popcount_xla,
+)
 from ..kernels.tm_popcount.ops import plan_to_popcount_operands
-from ..kernels.tuning import choose_blocks
 from .capacity import CapacityExceeded, CapacityPlan
 from .engine import EngineBase, _private_jit, register_engine
 
@@ -173,13 +178,15 @@ def _popcount_engine_xla(lit_idx, last, mask_pos, mask_neg, x_staged):
 
 def _popcount_engine_pallas(
     lit_idx, last, mask_pos, mask_neg, x_staged,
-    *, block_instructions, block_words, interpret,
+    *, weight_planes, block_instructions, block_words, interpret,
 ):
-    return tm_popcount.__wrapped__(
+    """The kernel on the program's resident layout (``kernel_operands``)."""
+    sums = tm_popcount_resident.__wrapped__(
         lit_idx, last, mask_pos, mask_neg, pack_literals(x_staged),
         block_instructions=block_instructions, block_words=block_words,
         interpret=interpret,
     )
+    return sum_weight_planes(sums.reshape(weight_planes, -1, sums.shape[1]))
 
 
 @register_engine("popcount", supports_donation=True, priority=30)
@@ -201,14 +208,18 @@ class PopcountEngine(EngineBase):
     instruction_metric = "includes"  # operand vectors hold includes only
     needs_decoded_plan = True
 
-    def __init__(self, plan: CapacityPlan, implementation: str | None = None):
+    def __init__(
+        self,
+        plan: CapacityPlan,
+        implementation: str | None = None,
+        interpret: bool = False,
+    ):
         super().__init__(plan)
+        on_tpu = jax.default_backend() == "tpu"
         if implementation is None:
             # the Pallas kernel is the TPU artifact; its interpret-mode
             # emulation loses to the bit-exact XLA twin everywhere else
-            implementation = (
-                "pallas" if jax.default_backend() == "tpu" else "xla"
-            )
+            implementation = "pallas" if on_tpu else "xla"
         if implementation not in ("pallas", "xla"):
             raise ValueError(
                 f"unknown implementation {implementation!r}; "
@@ -216,13 +227,20 @@ class PopcountEngine(EngineBase):
             )
         self.implementation = implementation
         if implementation == "pallas":
-            bi, bw = choose_blocks(
+            if not (on_tpu or interpret):
+                raise ValueError(
+                    f"implementation='pallas' needs a TPU, and the "
+                    f"backend is {jax.default_backend()!r}; pass "
+                    f"interpret=True to emulate the kernel, or "
+                    f"implementation='xla'"
+                )
+            bi, bw = kernel_blocks(
                 plan.instruction_capacity, plan.batch_words
             )
+            self._block_instructions = bi
             engine = functools.partial(
-                _popcount_engine_pallas,
-                block_instructions=bi, block_words=bw,
-                interpret=jax.default_backend() != "tpu",
+                _popcount_engine_pallas, weight_planes=plan.weight_planes,
+                block_instructions=bi, block_words=bw, interpret=interpret,
             )
         else:
             engine = _popcount_engine_xla
@@ -239,6 +257,10 @@ class PopcountEngine(EngineBase):
             l2_cap=2 * p.feature_capacity,
             weight_planes=p.weight_planes,
         )
+        if self.implementation == "pallas":
+            lit_idx, last, mask_pos, mask_neg = kernel_operands(
+                lit_idx, last, mask_pos, mask_neg, self._block_instructions
+            )
         # the reprogram is pure data movement: resident on-device until the
         # next swap, never retraced (fixed capacity shapes)
         return {
@@ -279,7 +301,7 @@ class ShardedEngine(EngineBase):
     def __init__(self, plan: CapacityPlan, mesh=None):
         super().__init__(plan)
         if mesh is None:
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
+            mesh = make_mesh((1, 1), ("data", "model"))
         self.mesh = mesh
         cfg = TMShardedConfig(
             name="serve", n_classes=plan.class_capacity,

@@ -44,14 +44,6 @@ _TYPE_RE = re.compile(
 _OPERAND_RE = re.compile(r"\((%[\w.\-]+(?:,\s*%[\w.\-]+)*)?\)")
 
 
-def cost_analysis_dict(cost) -> Dict[str, float]:
-    """Normalize Compiled.cost_analysis() output across jax versions:
-    0.4.x returns a one-element list of dicts, newer jax a flat dict."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def _shape_bytes(dtype: str, dims: str) -> int:
     n = 1
     if dims:

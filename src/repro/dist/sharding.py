@@ -24,7 +24,7 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Installed by set_activation_mesh; read by hint() and the MoE EP gate.
 _ACTIVATION_MESH: Optional[Mesh] = None
@@ -38,6 +38,33 @@ def set_activation_mesh(mesh: Optional[Mesh]) -> None:
 
 def _axis_sizes(mesh: Mesh) -> dict:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
+
+
+def make_mesh(axis_shapes, axis_names) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The rules in this module place arrays through ``NamedSharding`` and
+    ``with_sharding_constraint`` and let the partitioner propagate the
+    rest; ``jax.make_mesh`` defaults to ``Explicit`` axes, which refuse
+    such constraints and put shardings into array types.  Every mesh the
+    repo builds comes from here."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(AxisType.Auto,) * len(axis_names)
+    )
+
+
+def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes check off.
+
+    The TM executors and the MoE expert block write per-device outputs
+    that the check cannot prove (``psum`` over a subset of axes, outputs
+    that tile disjointly), so every caller in the repo turns it off; this
+    is the one place that says so."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def batch_axes(mesh: Mesh, B: int) -> Optional[Tuple[str, ...]]:

@@ -19,16 +19,12 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.6 moved shard_map out of experimental
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.train import sample_class_delta, sample_keys
 from ..models.api import family_for
 from ..optim import adamw
-from .sharding import _axis_sizes, batch_axes
+from .sharding import _axis_sizes, batch_axes, shard_map
 
 
 def opt_config_for(cfg) -> adamw.AdamWConfig:
@@ -143,7 +139,6 @@ def make_tm_train_step(tm_cfg, mesh, *, batch: int) -> Callable:
             mesh=mesh,
             in_specs=(state_spec, P(), P(bx, None), P(bx)),
             out_specs=state_spec,
-            check_rep=False,
         )(state, key, xb, yb)
 
     return jax.jit(step)

@@ -34,15 +34,11 @@ from typing import Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.6 moved shard_map out of experimental
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import _pad_to
 from ..core.tm import unpack_bits
-from .sharding import _axis_sizes, batch_axes
+from .sharding import _axis_sizes, batch_axes, shard_map
 
 # Includes processed per streaming step of the include-major executors
 # (the VMEM-resident instruction block; tests shrink it to force
@@ -244,7 +240,6 @@ def build_tm_sharded(cfg: TMShardedConfig, mesh) -> Tuple[Callable, tuple]:
             local, mesh=mesh,
             in_specs=(idx_spec, pol_spec, lit_spec),
             out_specs=out_spec,
-            check_rep=False,
         )(idx, pol, lits)
 
     specs = (
@@ -319,7 +314,7 @@ def operands_from_plan(cfg: TMShardedConfig, plan, X: np.ndarray, mesh):
 def dryrun_tm(name: str, *, multi_pod: bool = False, out_dir=None) -> dict:
     """Lower + compile the sharded TM on the production mesh and derive
     roofline terms (the --include-tm path of launch/dryrun.py)."""
-    from ..analysis.roofline import build_roofline, cost_analysis_dict
+    from ..analysis.roofline import build_roofline
     from ..launch.mesh import make_production_mesh
 
     cfg = TM_CONFIGS[name]
@@ -328,7 +323,7 @@ def dryrun_tm(name: str, *, multi_pod: bool = False, out_dir=None) -> dict:
     fn, specs = build_tm_sharded(cfg, mesh)
     with mesh:
         compiled = jax.jit(fn).lower(*specs).compile()
-    cost = cost_analysis_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     # useful work: one AND + one accumulate per (include, datapoint)
     includes = cfg.n_classes * cfg.n_clauses * cfg.lc_cap
     mf = 2.0 * includes * cfg.batch

@@ -19,11 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def _clause_matmul_kernel(a_ref, nl_ref, nonempty_ref, out_ref, acc_ref):
     k = pl.program_id(2)
@@ -81,7 +76,7 @@ def clause_matmul(
         out_specs=pl.BlockSpec((bc, bb), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((ncp, bp), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bc, bb), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

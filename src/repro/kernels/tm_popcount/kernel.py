@@ -27,13 +27,15 @@ instruction block:
      via ``jax.lax.population_count`` — the Fig 4.6 accumulate stage as
      32-way popcounts instead of 32 scalar adds.
 
-Layout mirrors ``tm_interp``: grid = (batch-word blocks [parallel],
-instruction blocks [arbitrary]); the packed clause accumulator and the
-class-sum bank live in VMEM scratch and persist across instruction blocks;
-the packed-literal panel (Feature Memory, Fig 4.5) stays VMEM-resident per
-batch block.  Block shapes default to the measured table in
-``kernels.tuning`` (a per-capacity synthesis-time choice, never a runtime
-recompile).
+Layout: grid = (batch-word blocks [parallel], instruction blocks
+[arbitrary]).  The operand vectors ``lit_idx``/``last`` sit in SMEM
+(scalar prefetch); the packed clause accumulator is VMEM scratch and the
+class-sum bank is the output block, both persisting across instruction
+blocks; the packed-literal panel (Feature Memory, Fig 4.5) stays
+VMEM-resident per batch block.  Every block is a whole array dim or whole
+8x128 tiles, as the TPU compiler requires (``tests/test_tpu_compile.py``).
+Block shapes default to ``kernels.tuning.choose_blocks`` (a per-capacity
+synthesis-time choice, never a runtime recompile).
 
 ``tm_popcount_xla`` is the same algorithm phrased as pure XLA ops (gather +
 segmented AND scan + bit transpose + popcount): the portable fast path the
@@ -46,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -54,9 +57,8 @@ from ..tuning import choose_blocks
 ONES = 0xFFFFFFFF  # python int: safe to close over in kernels
 
 # (shift, mask) rounds of the 32x32 bitplane transpose (Hacker's Delight
-# 7-3, vectorized); applied to a reversed word axis so the result follows
-# the little-endian convention used everywhere else in this repo:
-# out word b holds, at bit j, bit b of input word j.
+# 7-3, little-endian: the rounds swap the off-diagonal s x s sub-blocks of
+# every 2s x 2s block, so no bit or word reversal is needed)
 _TRANSPOSE_ROUNDS = (
     (16, 0x0000FFFF),
     (8, 0x00FF00FF),
@@ -66,21 +68,38 @@ _TRANSPOSE_ROUNDS = (
 )
 
 
+def bit_transpose32_rows(x: jax.Array, roll=jnp.roll) -> jax.Array:
+    """Transpose the 32x32 bit tile of every 32-row group of
+    ``uint32[R, W]`` (R % 32 == 0): out row ``32g + b`` holds, at bit j,
+    bit b of in row ``32g + j``.
+
+    Each round pairs row ``r`` with row ``r ^ s`` through two sublane
+    rolls and a row-parity select — whole-array ops only, no reshape,
+    stack or reversal, so the same body lowers inside a Mosaic kernel
+    (``roll=pltpu.roll``) and in XLA (``jnp.roll``).
+    """
+    n = x.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    for s, m in _TRANSPOSE_ROUNDS:
+        m = jnp.uint32(m)
+        up = roll(x, n - s, 0)  # up[r] = x[r + s]
+        down = roll(x, s, 0)  # down[r] = x[r - s]
+        lo = x ^ ((((x >> s) ^ up) & m) << s)  # rows with bit s clear
+        hi = x ^ (((down >> s) ^ x) & m)  # their partners
+        x = jnp.where((rows & s) == 0, lo, hi)
+    return x
+
+
 def bit_transpose32(x: jax.Array, axis: int) -> jax.Array:
     """Transpose 32x32 bit tiles held along ``axis`` (size 32) of uint32.
 
-    ``out[..., b, ...]`` has bit j equal to bit b of ``x[..., j, ...]``.
-    Five masked shift/XOR rounds, fully vectorized over all other axes.
+    ``out[..., b, ...]`` has bit j equal to bit b of ``x[..., j, ...]``,
+    vectorized over all other axes (``bit_transpose32_rows`` on the
+    flattened tile columns).
     """
-    x = jnp.moveaxis(x, axis, -1)[..., ::-1]
-    lead = x.shape[:-1]
-    for s, m in _TRANSPOSE_ROUNDS:
-        m = jnp.uint32(m)
-        y = x.reshape(*lead, 32 // (2 * s), 2, s)
-        a, b = y[..., 0, :], y[..., 1, :]
-        t = (a ^ (b >> s)) & m
-        x = jnp.stack([a ^ t, b ^ (t << s)], axis=-2).reshape(*lead, 32)
-    return jnp.moveaxis(x[..., ::-1], -1, axis)
+    y = jnp.moveaxis(x, axis, 0)
+    out = bit_transpose32_rows(y.reshape(32, -1)).reshape(y.shape)
+    return jnp.moveaxis(out, 0, axis)
 
 
 def popcount_reduce(
@@ -100,7 +119,7 @@ def popcount_reduce(
     paper's bitwise-only execution contract.  Plane 0 of an all-ones
     weight vector reproduces the unit-weight banks bit-exactly."""
     i, w = emit_words.shape
-    planes = bit_transpose32(emit_words.reshape(i // 32, 32, w), axis=1)
+    planes = bit_transpose32_rows(emit_words).reshape(i // 32, 32, w)
     # planes[c, b, w] bit j = clause-output bit b (datapoint 32w+b) of
     # instruction 32c+j; select per class with one AND, count with popcount
     if mask_pos.ndim == 2:
@@ -126,40 +145,107 @@ def popcount_reduce(
 
 
 def _tm_popcount_kernel(
-    lit_idx_ref, last_ref, mask_pos_ref, mask_neg_ref, lits_ref,
-    out_ref, acc_ref, emit_ref, sums_ref,
+    lit_idx_ref, last_ref,  # SMEM (scalar prefetch): int32[I_pad]
+    mask_pos_ref, mask_neg_ref,  # VMEM uint32[bi, banks], one row per inst
+    lits_ref,  # VMEM uint32[L2, bw] — Feature Memory panel
+    out_ref,  # VMEM int32[banks, 32, bw] — the class-sum bank
+    acc_ref, emit_ref,  # VMEM scratch uint32[1, bw], uint32[bi, bw]
 ):
-    bi = lit_idx_ref.shape[0]
-    bw = lits_ref.shape[1]
+    bi, bw = emit_ref.shape
+    base = pl.program_id(1) * bi
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_ref[...] = jnp.full((1, bw), jnp.uint32(ONES), jnp.uint32)
-        sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.int32)
-
-    lit_idx = lit_idx_ref[...]
-    last = last_ref[...]
-    lits = lits_ref[...]  # [L2, BW] uint32 — Feature Memory panel
+        acc_ref[...] = jnp.full(acc_ref.shape, ONES, jnp.uint32)
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.int32)
 
     def body(t, acc):
-        word = jax.lax.dynamic_index_in_dim(
-            lits, lit_idx[t], axis=0, keepdims=False
-        )  # [BW] — Literal Select
-        acc = acc & word  # Clause Compute: packed AND, nothing expanded
-        emit = last[t] == 1
-        pl.store(
-            emit_ref,
-            (pl.dslice(t, 1), slice(None)),
-            jnp.where(emit, acc, jnp.uint32(0))[None, :],
-        )
-        return jnp.where(emit, jnp.full_like(acc, jnp.uint32(ONES)), acc)
+        # Literal Select + Clause Compute: one packed AND per include
+        acc = acc & lits_ref[pl.ds(lit_idx_ref[base + t], 1), :]
+        emit = last_ref[base + t] == 1
+        emit_ref[pl.ds(t, 1), :] = jnp.where(emit, acc, jnp.uint32(0))
+        return jnp.where(emit, jnp.uint32(ONES), acc)
 
-    acc_ref[...] = jax.lax.fori_loop(0, bi, body, acc_ref[0, :])[None, :]
-    # one bitplane transpose + popcount reduction per instruction block
-    sums_ref[...] += popcount_reduce(
-        emit_ref[...], mask_pos_ref[...], mask_neg_ref[...]
+    acc_ref[...] = jax.lax.fori_loop(0, bi, body, acc_ref[...])
+    # one bitplane transpose + popcount reduction per instruction block:
+    # row 32c+b of ``planes`` holds, at bit j, datapoint b's output of
+    # instruction 32c+j; each mask row selects the same 32 instructions
+    planes = bit_transpose32_rows(emit_ref[...], pltpu.roll)
+    for m in range(out_ref.shape[0]):
+        d = jax.lax.population_count(
+            planes & mask_pos_ref[:, m : m + 1]
+        ).astype(jnp.int32) - jax.lax.population_count(
+            planes & mask_neg_ref[:, m : m + 1]
+        ).astype(jnp.int32)
+        out_ref[m] += d.reshape(bi // 32, 32, bw).sum(axis=0)
+
+
+def kernel_blocks(
+    i_cap: int,
+    n_words: int,
+    block_instructions: int | None = None,
+    block_words: int | None = None,
+) -> tuple[int, int]:
+    """Validated ``(bi, bw)`` for the kernel at one capacity point.
+
+    Unset blocks come from ``kernels.tuning.choose_blocks``.
+    ``block_instructions`` must be a positive multiple of 32 (the class
+    masks pack 32 instructions per word) and is clipped to the 32-aligned
+    instruction depth; ``block_words`` is clipped to the word count and
+    must then be all of it or a multiple of 128 (a block's last dim is a
+    whole array dim or whole 128-lane tiles)."""
+    if block_instructions is None or block_words is None:
+        auto_bi, auto_bw = choose_blocks(i_cap, n_words)
+        if block_instructions is None:
+            block_instructions = auto_bi
+        if block_words is None:
+            block_words = auto_bw
+    if block_instructions <= 0 or block_instructions % 32:
+        raise ValueError(
+            f"block_instructions must be a positive multiple of 32, got "
+            f"{block_instructions}"
+        )
+    bw = min(block_words, n_words)
+    if bw <= 0 or (bw != n_words and bw % 128):
+        raise ValueError(
+            f"block_words must cover all {n_words} words or be a positive "
+            f"multiple of 128, got {block_words}"
+        )
+    return min(block_instructions, -(-i_cap // 32) * 32), bw
+
+
+def kernel_operands(lit_idx, last_flag, mask_pos, mask_neg, block_instructions):
+    """Program operands -> the kernel's layout (numpy or jax arrays).
+
+    The operand vectors are padded to ``I_pad``, a whole number of
+    instruction blocks (padded instructions AND row 0 and never emit).
+    The class masks (``[banks, chunks]``, or ``[P, m_cap, chunks]`` with
+    the planes flattened into banks) become one row per instruction,
+    ``[I_pad, banks]`` with row t = chunk t // 32, so an instruction block
+    is a sublane-aligned row block of them.  A serving engine builds this
+    once per program and keeps it resident."""
+    xp = np if isinstance(mask_pos, np.ndarray) else jnp
+    i_cap = lit_idx.shape[0]
+    i_pad = -(-i_cap // block_instructions) * block_instructions
+
+    def rows(m):
+        m = m.reshape(-1, m.shape[-1])
+        m = xp.pad(m, ((0, 0), (0, i_pad // 32 - m.shape[1])))
+        return xp.repeat(m.T, 32, axis=0)
+
+    return (
+        xp.pad(lit_idx, (0, i_pad - i_cap)),
+        xp.pad(last_flag, (0, i_pad - i_cap)),
+        rows(mask_pos),
+        rows(mask_neg),
     )
-    out_ref[...] = sums_ref[...]
+
+
+def sum_weight_planes(sums: jax.Array) -> jax.Array:
+    """int32[P, m_cap, B] per-plane sums -> int32[m_cap, B] with shifted
+    adds (``<< b``), keeping the weighted path multiply-free."""
+    shifts = jnp.arange(sums.shape[0], dtype=jnp.int32)[:, None, None]
+    return jnp.left_shift(sums, shifts).sum(axis=0)
 
 
 @functools.partial(
@@ -178,76 +264,85 @@ def tm_popcount(
 ) -> jax.Array:
     """Popcount-bitplane inference -> int32[m_cap, W*32] class sums.
 
-    Block shapes default to the measured ``kernels.tuning`` table for this
-    capacity point; ``block_instructions`` must be a multiple of 32 (the
-    class masks pack 32 instructions per word).
+    Blocks are checked and defaulted by ``kernel_blocks``; the operands
+    are laid out by ``kernel_operands`` on every call (a serving engine
+    does that once per program and calls ``tm_popcount_resident``).
 
     3-D masks (``[P, m_cap, chunks]``, repro.prune weighted clauses) run
     the SAME kernel with the plane axis flattened into the class axis —
     the kernel popcounts ``P * m_cap`` banks — and the per-plane sums are
-    combined outside with shifted adds (``<< b``), keeping the kernel body
+    combined outside by ``sum_weight_planes``, keeping the kernel body
     untouched and the whole path multiply-free.
     """
-    if mask_pos.ndim == 3:
-        p, m_cap, chunks = mask_pos.shape
-        sums = tm_popcount(
-            lit_idx, last_flag,
-            mask_pos.reshape(p * m_cap, chunks),
-            mask_neg.reshape(p * m_cap, chunks),
-            packed_lits,
-            block_instructions=block_instructions,
-            block_words=block_words, interpret=interpret,
-        ).reshape(p, m_cap, -1)
-        shifts = jnp.arange(p, dtype=jnp.int32)[:, None, None]
-        return jnp.left_shift(sums, shifts).sum(axis=0)
-    i_cap = lit_idx.shape[0]
-    m_cap = mask_pos.shape[0]
-    l2, w = packed_lits.shape
-    if block_instructions is not None and block_instructions % 32:
-        raise ValueError(
-            f"block_instructions must be a multiple of 32, got "
-            f"{block_instructions}"
-        )
-    if block_instructions is None or block_words is None:
-        auto_bi, auto_bw = choose_blocks(i_cap, w)
-        block_instructions = block_instructions or auto_bi
-        block_words = block_words or auto_bw
-    # clip to the 32-aligned instruction depth; both operands are 32-aligned
-    bi = max(32, min(block_instructions, -(-i_cap // 32) * 32))
-    bw = min(block_words, w)
-    i_pad = -(-i_cap // bi) * bi
-    w_pad = -(-w // bw) * bw
-
-    def padi(a):  # padded instructions: AND row 0 forever, never emit
-        return jnp.pad(a, (0, i_pad - i_cap))
-
-    lit_idx, last_flag = padi(lit_idx), padi(last_flag)
-    mask_pos, mask_neg = (
-        jnp.pad(m, ((0, 0), (0, i_pad // 32 - m.shape[1])))
-        for m in (mask_pos, mask_neg)
+    bi, bw = kernel_blocks(
+        lit_idx.shape[0], packed_lits.shape[1], block_instructions,
+        block_words,
     )
-    packed_lits = jnp.pad(packed_lits, ((0, 0), (0, w_pad - w)))
+    sums = tm_popcount_resident.__wrapped__(
+        *kernel_operands(lit_idx, last_flag, mask_pos, mask_neg, bi),
+        packed_lits, block_instructions=bi, block_words=bw,
+        interpret=interpret,
+    )
+    if mask_pos.ndim == 3:
+        p = mask_pos.shape[0]
+        return sum_weight_planes(sums.reshape(p, -1, sums.shape[1]))
+    return sums
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_instructions", "block_words", "interpret")
+)
+def tm_popcount_resident(
+    lit_idx: jax.Array,  # int32[I_pad]
+    last_flag: jax.Array,  # int32[I_pad]
+    mask_pos: jax.Array,  # uint32[I_pad, banks]
+    mask_neg: jax.Array,  # uint32[I_pad, banks]
+    packed_lits: jax.Array,  # uint32[L2, W]
+    *,
+    block_instructions: int,
+    block_words: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """The kernel on operands already in ``kernel_operands``' layout, with
+    blocks from ``kernel_blocks`` -> int32[banks, W*32] class sums.
+
+    ``lit_idx``/``last_flag`` ride in SMEM as scalar prefetch (read once
+    per instruction); each grid step reads a ``(bi, banks)`` row block of
+    the masks."""
+    bi, bw = block_instructions, block_words
+    i_pad, banks = mask_pos.shape
+    l2, w = packed_lits.shape
+    w_pad = -(-w // bw) * bw
 
     out = pl.pallas_call(
         _tm_popcount_kernel,
-        grid=(w_pad // bw, i_pad // bi),
-        in_specs=[
-            pl.BlockSpec((bi,), lambda j, i: (i,)),
-            pl.BlockSpec((bi,), lambda j, i: (i,)),
-            pl.BlockSpec((m_cap, bi // 32), lambda j, i: (0, i)),
-            pl.BlockSpec((m_cap, bi // 32), lambda j, i: (0, i)),
-            pl.BlockSpec((l2, bw), lambda j, i: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((m_cap, bw * 32), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((m_cap, w_pad * 32), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((1, bw), jnp.uint32),  # packed clause accumulator
-            pltpu.VMEM((bi, bw), jnp.uint32),  # block emit buffer
-            pltpu.VMEM((m_cap, bw * 32), jnp.int32),  # class-sum bank
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(w_pad // bw, i_pad // bi),
+            in_specs=[
+                pl.BlockSpec((bi, banks), lambda j, i, *_: (i, 0)),
+                pl.BlockSpec((bi, banks), lambda j, i, *_: (i, 0)),
+                pl.BlockSpec((l2, bw), lambda j, i, *_: (0, j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (banks, 32, bw), lambda j, i, *_: (0, 0, j)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((1, bw), jnp.uint32),  # packed clause accumulator
+                pltpu.VMEM((bi, bw), jnp.uint32),  # block emit buffer
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((banks, 32, w_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(lit_idx, last_flag, mask_pos, mask_neg, packed_lits)
-    return out[:, : w * 32]
+    )(
+        lit_idx, last_flag, mask_pos, mask_neg,
+        jnp.pad(packed_lits, ((0, 0), (0, w_pad - w))),
+    )
+    # out[m, b, w] is datapoint 32w + b
+    return out[:, :, :w].transpose(0, 2, 1).reshape(banks, w * 32)
 
 
 def _segmented_and_scan(sel: jax.Array, start: jax.Array) -> jax.Array:

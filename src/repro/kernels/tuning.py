@@ -10,62 +10,44 @@ The kernels in this package tile their grids with two knobs:
 
 The right choice depends only on the *capacity* point (instruction depth x
 batch words) — a synthesis-time property, never on runtime model contents —
-so a small measured table is enough: no search at trace time, no cache
-misses at serve time.  ``DEFAULT_TABLE`` was measured with
-``measure_blocks`` over the tm_popcount kernel (interpret mode on the CPU
-container; re-measure on real TPU hardware with ``python -m
-repro.kernels.tuning``).  Rows are matched first-fit, so keep them sorted
-from smallest to largest capacity.
+so no search at trace time and no cache misses at serve time.  Today one
+shape serves every capacity point: it must be one the TPU compiler accepts
+(a word block is all of the words or a multiple of 128 lanes, an
+instruction block a multiple of 32 rows), and ``tests/test_tpu_compile.py``
+compiles the kernel for a described TPU v5e at the capacity points the repo
+serves.  It is compile-checked, not timed; ``measure_blocks`` times
+candidates on a TPU (``python -m repro.kernels.tuning``) once a benchmark
+needs a second shape.
 """
 
 from __future__ import annotations
 
-from typing import Iterable, Optional, Sequence, Tuple
+from typing import Iterable, Tuple
 
 BlockChoice = Tuple[int, int]  # (block_instructions, block_words)
 
-# (max_instructions, max_words) -> (block_instructions, block_words);
-# ``None`` bounds match anything (the final row is the fallback).
-DEFAULT_TABLE: Tuple[Tuple[Optional[int], Optional[int], int, int], ...] = (
-    # measured 2026-07 (CPU interpret, python -m repro.kernels.tuning):
-    # deep word tiles amortize the per-block bitplane transpose; small
-    # instruction blocks win only at shallow instruction depths
-    (256, 1, 128, 1),
-    (256, None, 256, 4),
-    (1024, 2, 256, 2),
-    (1024, None, 512, 8),
-    (4096, 4, 256, 4),
-    (None, None, 512, 8),
-)
+# block_words clips to the word count, so up to 128 words the word axis is
+# one block; wider batches tile in whole 128-lane blocks
+BLOCK_INSTRUCTIONS, BLOCK_WORDS = 512, 128
 
 
 def _ceil32(n: int) -> int:
     return max(32, -(-n // 32) * 32)
 
 
-def choose_blocks(
-    n_instructions: int,
-    n_words: int,
-    table: Sequence[Tuple[Optional[int], Optional[int], int, int]] = DEFAULT_TABLE,
-) -> BlockChoice:
-    """Pick ``(block_instructions, block_words)`` for a capacity point.
-
-    First-fit over ``table``; the returned block_instructions is clipped to
-    the (32-aligned) instruction depth and block_words to the word count,
-    so the caller can pass the choice straight to the kernel.
-    """
+def choose_blocks(n_instructions: int, n_words: int) -> BlockChoice:
+    """Pick ``(block_instructions, block_words)`` for a capacity point,
+    clipped to the (32-aligned) instruction depth and to the word count,
+    so the caller can pass the choice straight to the kernel."""
     if n_instructions <= 0 or n_words <= 0:
         raise ValueError(
             f"capacity must be positive, got {n_instructions} instructions "
             f"x {n_words} words"
         )
-    for max_i, max_w, bi, bw in table:
-        if (max_i is None or n_instructions <= max_i) and (
-            max_w is None or n_words <= max_w
-        ):
-            return min(bi, _ceil32(n_instructions)), min(bw, n_words)
-    # defensive: a table without a (None, None) fallback row
-    return min(512, _ceil32(n_instructions)), min(4, n_words)
+    return (
+        min(BLOCK_INSTRUCTIONS, _ceil32(n_instructions)),
+        min(BLOCK_WORDS, n_words),
+    )
 
 
 def measure_blocks(
@@ -73,21 +55,20 @@ def measure_blocks(
     n_words: int,
     *,
     candidates: Iterable[BlockChoice] = (
-        (128, 1), (128, 2), (256, 1), (256, 2), (256, 4),
-        (512, 1), (512, 2), (512, 4), (512, 8),
+        (128, 128), (256, 128), (512, 128), (1024, 128),
     ),
     m_cap: int = 16,
     l2: int = 256,
     repeats: int = 10,
-    interpret: bool = True,
+    interpret: bool = False,
     seed: int = 0,
 ) -> Tuple[BlockChoice, dict]:
     """Time the tm_popcount kernel per candidate block shape at one
     capacity point -> (best choice, {choice: median_seconds}).
 
-    Used offline to (re)generate ``DEFAULT_TABLE``; not called on any hot
-    path.  ``interpret=True`` measures the CPU emulation — only relative
-    ordering is meaningful there; on a TPU pass ``interpret=False``.
+    Used offline to choose ``choose_blocks``' shapes; not called on any
+    hot path.  Run it on a TPU: an interpret-mode timing measures the CPU
+    emulation, not the kernel.
     """
     import time
 
@@ -111,7 +92,7 @@ def measure_blocks(
 
     timings: dict = {}
     for bi, bw in candidates:
-        if bi > i_cap or bw > n_words:
+        if bi > i_cap:
             continue
         fn = lambda: tm_popcount(  # noqa: E731
             *args, block_instructions=bi, block_words=bw, interpret=interpret
@@ -132,7 +113,7 @@ def measure_blocks(
     return best, timings
 
 
-def _main() -> None:  # pragma: no cover - offline table regeneration
+def _main() -> None:  # pragma: no cover - offline block timing
     points = [(256, 1), (256, 4), (1024, 2), (1024, 8), (4096, 4)]
     print("capacity (instructions x words) -> best (bi, bw)  [median us]")
     for i_cap, w in points:

@@ -51,36 +51,6 @@ def _memory_dict(mem) -> dict:
     return out
 
 
-class _CompiledCell:
-    """Thin adapter over jax's Compiled: ``cost_analysis`` returns one flat
-    dict on every jax version (0.4.x returns a one-element list)."""
-
-    def __init__(self, compiled):
-        self._compiled = compiled
-
-    def cost_analysis(self):
-        from ..analysis.roofline import cost_analysis_dict
-
-        return cost_analysis_dict(self._compiled.cost_analysis())
-
-    def __call__(self, *args, **kwargs):
-        return self._compiled(*args, **kwargs)
-
-    def __getattr__(self, name):
-        return getattr(self._compiled, name)
-
-
-class _LoweredCell:
-    def __init__(self, lowered):
-        self._lowered = lowered
-
-    def compile(self):
-        return _CompiledCell(self._lowered.compile())
-
-    def __getattr__(self, name):
-        return getattr(self._lowered, name)
-
-
 def lower_cell(cfg, shape: ShapeSpec, mesh):
     """Build the jitted step for this cell and lower it (abstract only).
 
@@ -115,12 +85,12 @@ def _lower_cell(cfg, shape: ShapeSpec, mesh):
             donate_argnums=(0, 1),
         )
         with mesh:
-            return _LoweredCell(jitted.lower(p_specs, o_specs, in_specs))
+            return jitted.lower(p_specs, o_specs, in_specs)
     if shape.kind == "prefill":
         step = make_prefill_step(cfg)
         jitted = jax.jit(step, in_shardings=(p_sh, in_sh))
         with mesh:
-            return _LoweredCell(jitted.lower(p_specs, in_specs))
+            return jitted.lower(p_specs, in_specs)
     # decode
     c_specs = fam.cache_specs(cfg, shape)
     c_sh = shd.cache_shardings(cfg, mesh, shape, c_specs)
@@ -136,7 +106,7 @@ def _lower_cell(cfg, shape: ShapeSpec, mesh):
         donate_argnums=(1,),
     )
     with mesh:
-        return _LoweredCell(jitted.lower(p_specs, c_specs, in_specs))
+        return jitted.lower(p_specs, c_specs, in_specs)
 
 
 def _unit_count(cfg) -> int:

@@ -7,7 +7,7 @@ test environment (1 device) decide what exists.
 
 from __future__ import annotations
 
-import jax
+from ..dist.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,9 +18,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CI-scale sharding tests (requires host device count)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

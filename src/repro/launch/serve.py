@@ -86,7 +86,7 @@ def main():
     args = ap.parse_args()
 
     cfg = get(args.arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     # decode cache capacity (prompt + generation) is fixed at construction
     server = Server(cfg, mesh, batch=args.batch, prompt_cap=args.prompt_len,
                     gen_cap=args.gen)

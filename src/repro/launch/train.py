@@ -64,7 +64,7 @@ def main():
 
     cfg = get(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = shd.make_mesh((d, m), ("data", "model"))
     jitted, p_sh, o_sh, in_sh, opt_cfg, shape = build(
         cfg, mesh, seq=args.seq, batch=args.batch
     )

@@ -113,13 +113,9 @@ def moe_ffn_ep(p: Dict[str, Array], x: Array, cfg: ArchConfig, mesh) -> Array:
     are summed with one psum over ``model`` — replacing the GSPMD
     replicate+all-reduce of the [E, C, D] dispatch buffer (which dominated
     the baseline collective term) with a [T_local, D] reduction."""
-    try:  # jax >= 0.6 moved shard_map out of experimental
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..dist.sharding import batch_axes
+    from ..dist.sharding import batch_axes, shard_map
 
     B, S, D = x.shape
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -180,5 +176,4 @@ def moe_ffn_ep(p: Dict[str, Array], x: Array, cfg: ArchConfig, mesh) -> Array:
             P("model", None, None),
         ),
         out_specs=x_spec,
-        check_rep=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])

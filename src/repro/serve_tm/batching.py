@@ -32,7 +32,7 @@ import heapq
 import math
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -210,7 +210,9 @@ class RequestHandle:
 
     def _fill(
         self, lo: int, preds: np.ndarray, sums: Optional[np.ndarray] = None
-    ) -> None:
+    ) -> bool:
+        """Write rows into the result; True when they complete the request.
+        Waiters are woken by the caller (``Batcher.demux``)."""
         self.predictions[lo : lo + preds.shape[0]] = preds
         if sums is not None:
             if self.class_sums is None:
@@ -221,7 +223,7 @@ class RequestHandle:
         self._filled += preds.shape[0]
         if self.done:
             self.completed_at = time.perf_counter()
-            self._signal_terminal()
+        return self.done
 
     def _expire(self, now: float) -> None:
         self.expired_at = now
@@ -437,15 +439,23 @@ class Batcher:
         spans: List[Span],
         preds: np.ndarray,
         sums: Optional[np.ndarray] = None,
+        record: Optional[Callable[[List[RequestHandle]], None]] = None,
     ) -> int:
         """Scatter engine predictions (and, when given, the class-sum rows
         the drift monitor taps) back into the request handles.  Returns how
-        many requests COMPLETED with this batch."""
-        completed = 0
+        many requests COMPLETED with this batch.
+
+        ``record`` sees the completed handles BEFORE their waiters wake,
+        so a caller that reads metrics after ``result()`` returns always
+        finds its request counted."""
+        completed = []
         for handle, lo, hi, req_lo in spans:
-            handle._fill(
+            if handle._fill(
                 req_lo, preds[lo:hi], None if sums is None else sums[lo:hi]
-            )
-            if handle.done:
-                completed += 1
-        return completed
+            ):
+                completed.append(handle)
+        if record is not None:
+            record(completed)
+        for handle in completed:
+            handle._signal_terminal()
+        return len(completed)

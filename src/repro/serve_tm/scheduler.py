@@ -250,14 +250,15 @@ class Scheduler:
                     "failed with EngineFault", slot, len(spans),
                 )
                 return X.shape[0]
-            completed = Batcher.demux(spans, preds, sums)
-            server.metrics.record_batch(
-                X.shape[0], server.capacity.batch_capacity, dt, completed
-            )
-            for handle, _, _, _ in spans:
-                if handle.failed:
-                    continue  # a prior batch already failed this request
-                if handle.done and handle.latency_s is not None:
+
+            def record(completed):
+                server.metrics.record_batch(
+                    X.shape[0], server.capacity.batch_capacity, dt,
+                    len(completed),
+                )
+                for handle in completed:
+                    if handle.failed:
+                        continue  # a prior batch already failed it
                     server.metrics.record_request_latency(handle.latency_s)
                     server.metrics.record_lane_completion(
                         handle.priority,
@@ -265,6 +266,8 @@ class Scheduler:
                         handle.latency_s,
                         missed=handle.missed_deadline,
                     )
+
+            Batcher.demux(spans, preds, sums, record=record)
             server._check_no_recompile()
             return X.shape[0]
 

@@ -333,8 +333,9 @@ def test_engine_auto_selection_is_deterministic():
     assert select_engine(plan) == "popcount"
     assert all(select_engine(plan) == "popcount" for _ in range(5))
     # a mesh makes the mesh-consuming plugin the eligible set
-    import jax
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.dist.sharding import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert select_engine(plan, mesh=mesh) == "sharded"
     acc = Accelerator(plan)
     assert acc.engine.name == "popcount"
@@ -365,6 +366,28 @@ def test_make_engine_uniform_construction_and_options():
     assert ENGINES["sharded"].needs_mesh
     assert not ENGINES["plan"].needs_mesh
     assert ENGINES["popcount"].supports_donation
+
+
+def test_pallas_popcount_needs_a_tpu_or_explicit_interpret():
+    """No hidden fallback: off-TPU the Pallas kernel is refused unless
+    the caller asks for interpret mode, which is then bit-exact with the
+    XLA twin through the engine path."""
+    plan = CapacityPlan(
+        instruction_capacity=256, feature_capacity=32, class_capacity=4,
+        clause_capacity=8, include_capacity=8, batch_words=1,
+    )
+    with pytest.raises(ValueError, match="needs a TPU"):
+        make_engine("popcount", plan, implementation="pallas")
+    emu = make_engine(
+        "popcount", plan, implementation="pallas", interpret=True
+    )
+    twin = make_engine("popcount", plan, implementation="xla")
+    cfg = TMConfig(n_classes=4, n_clauses=8, n_features=32)
+    rng = np.random.default_rng(5)
+    model = encode(cfg, rng.random((4, 8, 64)) < 0.1)
+    x = rng.integers(0, 2, (20, 32)).astype(np.uint8)
+    got = emu.class_sums(emu.program(model), x)
+    assert np.array_equal(got, twin.class_sums(twin.program(model), x))
 
 
 def test_donation_warning_suppression_is_scoped_to_dispatch():

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import TokenStream, TokenStreamConfig
+from repro.dist.sharding import make_mesh
 from repro.runtime_ft.supervisor import (
     HeartbeatTracker,
     StragglerMonitor,
@@ -120,7 +121,7 @@ def test_elastic_reshard(tmp_path):
     ckpt = CheckpointManager(tmp_path)
     st = _state()
     ckpt.save(1, st)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), st)

@@ -50,7 +50,7 @@ def test_param_shardings_degenerate_mesh():
     from repro.configs.registry import get
     from repro.models.api import abstract_params
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     cfg = get("starcoder2-7b")
     specs = abstract_params(cfg)
     sh = shd.param_shardings(cfg, mesh, specs)
@@ -81,7 +81,7 @@ def test_build_tm_sharded_matches_oracle():
     oracle = np.asarray(batch_class_sums(tmcfg, state, jnp.asarray(X)))
     plan = decode_to_plan(encode(tmcfg, np.asarray(acts)))
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     Lc = int(max(
         (plan.clause_id == c).sum() for c in range(plan.n_clauses_total)
     ))
@@ -105,7 +105,7 @@ def test_operands_capacity_errors():
     tmcfg = TMConfig(n_classes=2, n_clauses=4, n_features=10)
     acts = rng.random((2, 4, 20)) < 0.5
     plan = decode_to_plan(encode(tmcfg, np.asarray(acts)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     cfg = tms.TMShardedConfig(
         name="t", n_classes=2, n_clauses=4, n_features=10, batch=32,
         include_cap=1,  # too small for density 0.5
@@ -123,7 +123,7 @@ def test_dryrun_lowers_smoke_cell():
     from repro.dist import sharding as shd_mod
     from repro.launch.dryrun import lower_cell
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     cfg = get("stablelm-3b-smoke")
     try:
         lowered = lower_cell(cfg, ShapeSpec("t", 64, 8, "train"), mesh)

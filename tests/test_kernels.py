@@ -18,7 +18,11 @@ from repro.kernels.tm_interp.ops import (
 from repro.kernels.tm_interp.ref import tm_interp_ref
 from repro.kernels.tm_popcount.kernel import (
     bit_transpose32,
+    kernel_blocks,
+    kernel_operands,
+    sum_weight_planes,
     tm_popcount,
+    tm_popcount_resident,
     tm_popcount_xla,
 )
 from repro.kernels.tm_popcount.ops import (
@@ -26,7 +30,7 @@ from repro.kernels.tm_popcount.ops import (
     tm_popcount_class_sums,
 )
 from repro.kernels.tm_popcount.ref import tm_popcount_ref
-from repro.kernels.tuning import DEFAULT_TABLE, choose_blocks
+from repro.kernels.tuning import choose_blocks
 
 rng = np.random.default_rng(11)
 
@@ -132,10 +136,11 @@ def test_bit_transpose32_spec_and_involution():
 @pytest.mark.parametrize(
     "M,C,F,B,bi,bw",
     [
-        (4, 12, 25, 64, 64, 1),
+        (4, 12, 25, 64, 64, 2),
         (3, 8, 100, 32, 128, 1),
-        (6, 20, 60, 128, 96, 2),
-        (2, 4, 10, 96, 32, 4),  # word blocking
+        (6, 20, 60, 128, 96, 4),
+        (2, 4, 10, 96, 32, 4),  # block_words clips to the word count
+        (2, 4, 10, 32 * 160, 32, 128),  # word blocking: two 128-word blocks
         (5, 6, 33, 32, 64, 1),  # i_cap not 32-aligned (padding path)
     ],
 )
@@ -223,12 +228,63 @@ def test_choose_blocks_table():
         bi, bw = choose_blocks(n_inst, n_words)
         assert bi % 32 == 0 and bi >= 32
         assert 1 <= bw <= n_words
+        assert bw == n_words or bw % 128 == 0  # a whole dim or whole tiles
         assert bi <= -(-n_inst // 32) * 32
-    # first-fit honors the measured table rows
-    assert choose_blocks(256, 1) == (128, 1)
-    assert choose_blocks(4096, 4, table=DEFAULT_TABLE) == (256, 4)
+    # the one shape, clipped to the capacity point
+    assert choose_blocks(256, 1) == (256, 1)
+    assert choose_blocks(17024, 4) == (512, 4)
+    assert choose_blocks(4096, 300) == (512, 128)
+    assert choose_blocks(40, 8) == (64, 8)
     with pytest.raises(ValueError, match="positive"):
         choose_blocks(0, 4)
+
+
+@pytest.mark.parametrize(
+    "bi,bw,match",
+    [
+        (64, 2, "block_words"),  # neither all 4 words nor 128-lane tiles
+        (64, 0, "block_words"),
+        (0, 4, "block_instructions"),
+        (48, 4, "block_instructions"),
+    ],
+)
+def test_tm_popcount_rejects_blocks_off_the_tiling(bi, bw, match):
+    """Blocks the TPU compiler would refuse are refused up front."""
+    lit_idx = jnp.zeros((64,), jnp.int32)
+    masks = jnp.zeros((2, 2), jnp.uint32)
+    lits = jnp.zeros((8, 4), jnp.uint32)
+    with pytest.raises(ValueError, match=match):
+        tm_popcount(lit_idx, lit_idx, masks, masks, lits,
+                    block_instructions=bi, block_words=bw, interpret=True)
+
+
+def test_tm_popcount_resident_layout_matches_per_call_layout():
+    """The serving engine's once-per-program layout (numpy
+    ``kernel_operands`` + ``tm_popcount_resident``) gives the same sums as
+    ``tm_popcount`` laying the operands out per call, weighted masks
+    included."""
+    cfg = TMConfig(n_classes=3, n_clauses=8, n_features=20)
+    acts = rng.random((3, 8, 40)) < 0.15
+    X = rng.integers(0, 2, (64, 20)).astype(np.uint8)
+    plan = decode_to_plan(encode(cfg, np.asarray(acts)))
+    lits = pack_interleaved_literals(jnp.asarray(X))
+    ops = plan_to_popcount_operands(plan, 200, 4, weight_planes=2)
+    bi, bw = kernel_blocks(200, lits.shape[1], block_instructions=64)
+    li, la, mp, mn = kernel_operands(*ops, bi)
+    assert li.shape == (256,) and mp.shape == (256, 8)
+    per_plane = tm_popcount_resident(
+        *(jnp.asarray(a) for a in (li, la, mp, mn)), lits,
+        block_instructions=bi, block_words=bw, interpret=True,
+    )
+    resident = sum_weight_planes(per_plane.reshape(2, 4, -1))
+    per_call = tm_popcount(
+        *(jnp.asarray(a) for a in ops), lits, block_instructions=64,
+        interpret=True,
+    )
+    assert (np.asarray(resident) == np.asarray(per_call)).all()
+    assert (np.asarray(per_call) == np.asarray(
+        tm_popcount_xla(*(jnp.asarray(a) for a in ops), lits)
+    )).all()
 
 
 @pytest.mark.parametrize(

@@ -66,8 +66,7 @@ def test_popcount_matches_interp_and_plan(case):
     )
     pc_args = tuple(jnp.asarray(a) for a in pc_ops) + (packed,)
     out_pallas = np.asarray(
-        tm_popcount(*pc_args, block_instructions=64, block_words=1,
-                    interpret=True)
+        tm_popcount(*pc_args, block_instructions=64, interpret=True)
     )
     out_xla = np.asarray(tm_popcount_xla(*pc_args))
 

@@ -20,6 +20,7 @@ from repro.core import (
 )
 from repro.core.compress import encode, validate_roundtrip
 from repro.data.pipeline import TMDatasetSpec, booleanized_tm_dataset
+from repro.dist.sharding import make_mesh
 from repro.dist.steps import make_tm_train_step
 from repro.recal import (
     Compressor,
@@ -79,7 +80,7 @@ def test_sharded_tm_train_step_matches_parallel_trainer():
     xb, yb = _random_batch(rng, 32, 6, 4)
     key = jax.random.key(5)
     ref = train_batch_parallel(cfg, init_state(cfg, key), key, xb, yb)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     step = make_tm_train_step(cfg, mesh, batch=32)
     out = step(init_state(cfg, key), key, xb, yb)
     assert jnp.array_equal(ref, out)
@@ -96,6 +97,7 @@ def test_sharded_tm_train_step_multidevice():
         [sys.executable, "-c", textwrap.dedent("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.core import TMConfig, init_state, train_batch_parallel
+            from repro.dist.sharding import make_mesh
             from repro.dist.steps import make_tm_train_step
             cfg = TMConfig(n_classes=4, n_clauses=8, n_features=6)
             rng = np.random.default_rng(0)
@@ -104,7 +106,7 @@ def test_sharded_tm_train_step_multidevice():
             key = jax.random.key(5)
             ref = train_batch_parallel(
                 cfg, init_state(cfg, key), key, xb, yb)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             step = make_tm_train_step(cfg, mesh, batch=32)
             out = step(init_state(cfg, key), key, xb, yb)
             assert jnp.array_equal(ref, out), "mesh step diverged"

@@ -390,10 +390,35 @@ def test_batch_formation_property_priority_and_expiry():
                 assert not h.expired
                 assert h.deadline is None or h.deadline > now
             assert X.shape[0] == sum(hi - lo for _, lo, hi, _ in spans)
+            # the engine's answer for the batch completes its requests
+            Batcher.demux(spans, np.zeros(X.shape[0], np.int32))
         for h, dl in handles:
             assert h.status == ("expired" if dl == "past" else "done")
 
     check()
+
+
+def test_demux_records_completions_before_waking_waiters():
+    """A caller that reads metrics as soon as ``result()`` returns finds
+    its request counted: ``demux``'s record hook runs before any waiter
+    of the batch can wake."""
+    b = Batcher(32)
+    h_done = RequestHandle(0, "s", 4, priority=PRIORITIES[0])
+    h_part = RequestHandle(1, "s", 40, priority=PRIORITIES[0])
+    b.enqueue(h_done, np.zeros((4, 8), np.uint8))
+    b.enqueue(h_part, np.zeros((40, 8), np.uint8))
+    X, spans = b.next_batch("s")
+    seen = []
+
+    def record(completed):
+        seen.extend(completed)
+        assert not any(h._terminal_evt.is_set() for h in completed)
+
+    preds = np.ones(X.shape[0], np.int32)
+    assert Batcher.demux(spans, preds, record=record) == 1
+    assert seen == [h_done] and h_done._terminal_evt.is_set()
+    assert (h_done.result() == 1).all()
+    assert not h_part.done and not h_part._terminal_evt.is_set()
 
 
 def test_async_submit_admission_control_overload():

@@ -34,8 +34,9 @@ def test_smoke_cells_compile_on_mesh():
         import jax
         from repro.configs.registry import get
         from repro.configs.base import ShapeSpec
+        from repro.dist.sharding import make_mesh
         from repro.launch.dryrun import lower_cell
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         for arch in ("starcoder2-7b", "moonshot-v1-16b-a3b", "zamba2-2.7b",
                      "whisper-medium", "xlstm-125m"):
             cfg = get(arch + "-smoke")
@@ -55,7 +56,7 @@ def test_multipod_axis_shards():
         from repro.configs.registry import get
         from repro.configs.base import ShapeSpec
         from repro.dist import sharding as shd
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = shd.make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get("stablelm-3b-smoke")
         assert shd.batch_axes(mesh, 8) == ("pod", "data")
         from repro.launch.dryrun import lower_cell
@@ -70,8 +71,9 @@ def test_tm_sharded_compiles():
     """The paper's multi-core TM on a mesh (classes x batch)."""
     out = _run("""
         import jax, dataclasses
+        from repro.dist.sharding import make_mesh
         from repro.dist.tm_sharded import TM_CONFIGS, build_tm_sharded
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = dataclasses.replace(TM_CONFIGS["tm-paper"], n_classes=2, batch=64)
         # adapt: model axis=2 shards 2 classes; data axis=4 shards batch
         fn, specs = build_tm_sharded(cfg, mesh)
@@ -103,7 +105,7 @@ def test_param_sharding_rules():
     from repro.dist import sharding as shd
     from repro.models.api import abstract_params
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     for arch in ("starcoder2-7b", "llama4-maverick-400b-a17b", "zamba2-2.7b",
                  "xlstm-125m", "whisper-medium"):
         cfg = get(arch)
@@ -128,7 +130,7 @@ def test_cache_sharding_rules_head_dims():
     from repro.models.api import family_for
     from repro.models.ssm import ssm_dims
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
 
     def specs_for(arch, batch=8):
         cfg = get(arch)

@@ -116,7 +116,7 @@ def test_moe_ep_matches_plain():
     x = jnp.asarray(rng.normal(size=(2, 16, D)), jnp.float32)
     shd.set_activation_mesh(None)
     y_plain = moe.moe_ffn(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     shd.set_activation_mesh(mesh)
     try:
         with mesh:

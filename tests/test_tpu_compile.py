@@ -1,0 +1,104 @@
+"""Compile the main path's kernels for a described TPU v5e, without a chip.
+
+The TPU compiler is installed with jaxlib; it compiles for a topology that
+is described (``v5e:2x2``) rather than attached.  These compiles refuse
+what CPU interpret mode accepts — block shapes off the 8x128 tiling,
+vector ops Mosaic cannot lower, kernels that overflow on-chip memory — so
+they guard every later change at the shapes the chip run serves:
+
+* ``tm_popcount`` (Pallas) at the MNIST capacity point, at the
+  ``CapacityPlan()`` default, and with two clause-weight planes;
+* ``tm_popcount_xla`` and the packed train step at the MNIST shape.
+
+Nothing runs; a compile that passes is not a chip run.  The topology is
+described inside a fixture (never at import: only one process at a time
+may load the TPU library), and the persistent compilation cache is off
+around these compiles, since an entry written for a described chip cannot
+be read back without one.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.tm import TMConfig
+from repro.kernels.tm_popcount.kernel import tm_popcount, tm_popcount_xla
+from repro.kernels.tm_train import fused_train_batch
+
+# The envelope CapacityPlan.for_models negotiates for the two MNIST-scale
+# models chip_smoke.py serves (benchmarks.tm_bench_common
+# .synthetic_mnist_scale, seeds 0 and 1), with batch_words=4.
+MNIST = dict(i_cap=17152, m_cap=10, f_cap=784, words=4)
+DEFAULT = dict(i_cap=4096, m_cap=16, f_cap=256, words=4)  # CapacityPlan()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    )
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _popcount_args(one_chip, i_cap, m_cap, f_cap, words, planes):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    masks = (planes, m_cap, -(-i_cap // 32))  # the engine's 3-D banks
+    return (
+        s((i_cap,), jnp.int32), s((i_cap,), jnp.int32),
+        s(masks, jnp.uint32), s(masks, jnp.uint32),
+        s((2 * f_cap, words), jnp.uint32),
+    )
+
+
+@pytest.mark.parametrize(
+    "point,planes",
+    [(MNIST, 1), (DEFAULT, 1), (MNIST, 2)],
+    ids=["mnist", "default", "mnist-weighted"],
+)
+def test_tm_popcount_compiles_for_v5e(one_chip, point, planes):
+    args = _popcount_args(one_chip, planes=planes, **point)
+    compiled = jax.jit(tm_popcount).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel, not XLA
+
+
+def test_tm_popcount_xla_compiles_for_v5e_mnist(one_chip):
+    args = _popcount_args(one_chip, planes=1, **MNIST)
+    jax.jit(tm_popcount_xla).lower(*args).compile()
+
+
+def test_fused_train_batch_compiles_for_v5e_mnist(one_chip):
+    cfg = TMConfig(n_classes=10, n_clauses=200, n_features=784)
+    batch = 32
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    args = (
+        jax.ShapeDtypeStruct(
+            (cfg.n_classes, cfg.n_clauses, cfg.n_features, 2), jnp.int8,
+            sharding=one_chip,
+        ),
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((batch, cfg.n_features), jnp.uint8,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
+    )
+    fused_train_batch.lower(cfg, *args).compile()

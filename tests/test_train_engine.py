@@ -23,6 +23,7 @@ import pytest
 from repro.accel.capacity import CapacityExceeded, CapacityPlan
 from repro.core.tm import TMConfig, init_state
 from repro.core.train import fit_step
+from repro.dist.sharding import make_mesh
 from repro.kernels.tm_train import (
     MAX_PACKED_STATES,
     check_packable,
@@ -48,7 +49,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _batch(rng, B, F, M):
@@ -387,10 +388,11 @@ def test_worker_legacy_sharded_shim():
         import jax
         import numpy as np
         from repro.core.tm import TMConfig
+        from repro.dist.sharding import make_mesh
         from repro.recal import RecalWorker
 
         cfg = TMConfig(n_classes=2, n_clauses=6, n_features=4)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with warnings.catch_warnings(record=True) as rec:
             warnings.simplefilter("always")
             w1 = RecalWorker(cfg, key=jax.random.key(0), mesh=mesh,
