@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - busy / window, from ``bench/trace_reduce.py``)."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or not t["devices"] or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
