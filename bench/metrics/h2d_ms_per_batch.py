@@ -1,0 +1,9 @@
+"""Host time per batch in staging the batch to the device (``tm.h2d``): the
+program's span summed over the traced window, over the ``tm.batch`` spans
+there (``bench/span_reduce.py``)."""
+
+from bench import span_reduce
+
+
+def read(run):
+    return span_reduce.ms_per_batch(run, "tm.h2d")

@@ -152,13 +152,17 @@ def make_engine(
     return cls(plan, **options)
 
 
-def _private_jit(fn, **jit_kwargs):
+def _private_jit(fn, name: str, **jit_kwargs):
     """jit over a FRESH closure: JAX keys its compilation cache on the
-    callable, so wrapping gives this engine instance its own cache."""
+    callable, so wrapping gives this engine instance its own cache.
+
+    ``name`` names the closure, and so the compiled program
+    (``jit_<name>``) and its device ops in a profiler trace."""
 
     def inner(*args, **kwargs):
         return fn(*args, **kwargs)
 
+    inner.__name__ = inner.__qualname__ = name
     return jax.jit(inner, **jit_kwargs)
 
 

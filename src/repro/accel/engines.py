@@ -41,6 +41,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import _pad_to
 from ..core.compress import CompressedModel, decode_to_plan
@@ -62,6 +63,7 @@ from ..kernels.tm_popcount.kernel import (
 from ..kernels.tm_popcount.ops import plan_to_popcount_operands
 from .capacity import CapacityExceeded, CapacityPlan
 from .engine import EngineBase, _private_jit, register_engine
+from .spans import D2H, H2D, LAUNCH
 
 
 @register_engine("interp", priority=10)
@@ -75,7 +77,8 @@ class InterpEngine(EngineBase):
     def __init__(self, plan: CapacityPlan):
         super().__init__(plan)
         self._fn = _private_jit(
-            interpret_stream.__wrapped__, static_argnames=("m_cap",)
+            interpret_stream.__wrapped__, "tm_interp_step",
+            static_argnames=("m_cap",),
         )
 
     def _program(self, model: CompressedModel, decoded=None) -> Dict[str, Any]:
@@ -132,7 +135,7 @@ class PlanEngine(EngineBase):
     def __init__(self, plan: CapacityPlan):
         super().__init__(plan)
         self._fn = _private_jit(
-            plan_class_sums.__wrapped__,
+            plan_class_sums.__wrapped__, "tm_plan_step",
             static_argnames=("n_clause_cap", "m_cap"),
         )
 
@@ -244,7 +247,9 @@ class PopcountEngine(EngineBase):
             )
         else:
             engine = _popcount_engine_xla
-        self._fn = _private_jit(engine, donate_argnums=(4,))
+        self._fn = _private_jit(
+            engine, "tm_popcount_step", donate_argnums=(4,)
+        )
 
     def _program(self, model: CompressedModel, decoded=None) -> Dict[str, Any]:
         p = self.plan
@@ -274,13 +279,16 @@ class PopcountEngine(EngineBase):
 
     def class_sums(self, prog: Dict[str, Any], x: np.ndarray) -> np.ndarray:
         B = x.shape[0]
-        # fresh device copy of the staging block; the engine donates it
-        staged = jnp.asarray(self._pad_x(x))
-        sums = self._dispatch(
-            prog["lit_idx"], prog["last"],
-            prog["mask_pos"], prog["mask_neg"], staged,
-        )
-        return np.asarray(sums)[: prog["n_classes"], :B].T
+        with TraceAnnotation(H2D):
+            # fresh device copy of the staging block; the engine donates it
+            staged = jnp.asarray(self._pad_x(x))
+        with TraceAnnotation(LAUNCH):
+            sums = self._dispatch(
+                prog["lit_idx"], prog["last"],
+                prog["mask_pos"], prog["mask_neg"], staged,
+            )
+        with TraceAnnotation(D2H):
+            return np.asarray(sums)[: prog["n_classes"], :B].T
 
 
 @register_engine("sharded", needs_mesh=True, priority=5)
@@ -313,7 +321,7 @@ class ShardedEngine(EngineBase):
         fn, _ = build_tm_sharded(cfg, mesh)
         # route through _private_jit like every other engine so the
         # compile_cache_size() == 1 contract is enforced uniformly
-        self._fn = _private_jit(fn)
+        self._fn = _private_jit(fn, "tm_sharded_step")
         self._Mp = _pad_to(
             plan.class_capacity, _axis_sizes(mesh).get("model", 1)
         )

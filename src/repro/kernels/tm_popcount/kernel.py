@@ -337,6 +337,7 @@ def tm_popcount_resident(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="tm_popcount",
     )(
         lit_idx, last_flag, mask_pos, mask_neg,
         jnp.pad(packed_lits, ((0, 0), (0, w_pad - w))),

@@ -77,7 +77,6 @@ class ServeMetrics:
         self.quarantines = 0     # circuit-breaker opened on this node
         self.probes = 0          # half-open probes admitted to this node
         self.engine_s: List[float] = []
-        self.request_latency_s: List[float] = []
         self.swap_s: List[float] = []
         self.recal_train_s: List[float] = []
         self.recal_compress_s: List[float] = []
@@ -98,9 +97,6 @@ class ServeMetrics:
         self.padded_rows += capacity
         self.engine_s.append(elapsed_s)
         self.requests_completed += completed
-
-    def record_request_latency(self, latency_s: float) -> None:
-        self.request_latency_s.append(latency_s)
 
     def record_lane_completion(
         self,
@@ -247,7 +243,9 @@ class ServeMetrics:
                 k: v * 1e6 for k, v in _pcts(self.engine_s).items()
             },
             "request_latency_us": {
-                k: v * 1e6 for k, v in _pcts(self.request_latency_s).items()
+                k: v * 1e6 for k, v in _pcts(
+                    [t for p in PRIORITIES for t in self.lane_latency_s[p]]
+                ).items()
             },
             "swap_us": {k: v * 1e6 for k, v in _pcts(self.swap_s).items()},
             "recals": self.recals,

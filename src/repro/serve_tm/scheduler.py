@@ -40,7 +40,9 @@ import time
 from typing import Dict, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from ..accel.spans import BATCH, DEMUX, FORM, WAIT
 from .batching import Batcher, PRIORITIES, PRIORITY_RANK
 
 logger = logging.getLogger(__name__)
@@ -219,57 +221,69 @@ class Scheduler:
         """Form + execute + demux ONE engine batch for ``slot``; returns
         the number of rows served.  Asserts zero recompilation after the
         batch — the no-resynthesis invariant holds per scheduler-formed
-        batch, not just per sync flush."""
+        batch, not just per sync flush.  The body and its phases are
+        profiler spans (``repro.accel.spans``)."""
         server = self.server
         with self.lock:
             if not server.batcher.pending_rows(slot):
                 return 0
-            entry = server.registry.get(slot)
-            X, spans = server.batcher.next_batch(
-                slot, out=server.executor.staging
-            )
-            self._record_shed()
-            if not spans:  # everything queued had already expired
-                return 0
-            t0 = time.perf_counter()
-            try:
-                sums = server.executor.class_sums(entry.program, X)
+            with TraceAnnotation(BATCH, slot=slot) as batch:
+                entry = server.registry.get(slot)
+                with TraceAnnotation(FORM):
+                    X, spans = server.batcher.next_batch(
+                        slot, out=server.executor.staging
+                    )
+                    self._record_shed()
+                if not spans:  # everything queued had already expired
+                    return 0
+                batch.set_metadata(rows=X.shape[0], requests=len(spans))
+                t0 = time.perf_counter()
+                try:
+                    sums = server.executor.class_sums(entry.program, X)
+                except Exception as cause:
+                    return self._fail_batch(slot, spans, cause, X.shape[0])
                 dt = time.perf_counter() - t0
-                preds = np.argmax(sums, axis=1).astype(np.int32)
-            except Exception as cause:
-                # a raising batch body must not strand its requests until
-                # their own timeouts: fail every handle in the batch with
-                # a structured error (slot + cause) and keep the loop —
-                # and the other slots' traffic — alive.
-                fault = EngineFault(slot, cause)
-                now = time.perf_counter()
-                for handle, _, _, _ in spans:
-                    handle._fail(fault, now)
-                logger.exception(
-                    "engine batch for slot %r failed; %d request(s) "
-                    "failed with EngineFault", slot, len(spans),
-                )
+
+                def record(completed):
+                    server.metrics.record_batch(
+                        X.shape[0], server.capacity.batch_capacity, dt,
+                        len(completed),
+                    )
+                    for handle in completed:
+                        if handle.failed:
+                            continue  # a prior batch already failed it
+                        server.metrics.record_lane_completion(
+                            handle.priority,
+                            handle.queue_delay_s or 0.0,
+                            handle.latency_s,
+                            missed=handle.missed_deadline,
+                        )
+
+                with TraceAnnotation(DEMUX):
+                    try:
+                        preds = np.argmax(sums, axis=1).astype(np.int32)
+                    except Exception as cause:
+                        return self._fail_batch(
+                            slot, spans, cause, X.shape[0])
+                    Batcher.demux(spans, preds, sums, record=record)
+                    server._check_no_recompile()
                 return X.shape[0]
 
-            def record(completed):
-                server.metrics.record_batch(
-                    X.shape[0], server.capacity.batch_capacity, dt,
-                    len(completed),
-                )
-                for handle in completed:
-                    if handle.failed:
-                        continue  # a prior batch already failed it
-                    server.metrics.record_request_latency(handle.latency_s)
-                    server.metrics.record_lane_completion(
-                        handle.priority,
-                        handle.queue_delay_s or 0.0,
-                        handle.latency_s,
-                        missed=handle.missed_deadline,
-                    )
-
-            Batcher.demux(spans, preds, sums, record=record)
-            server._check_no_recompile()
-            return X.shape[0]
+    @staticmethod
+    def _fail_batch(slot: str, spans, cause: Exception, rows: int) -> int:
+        """A raising batch body must not strand its requests until their
+        own timeouts: fail every handle in the batch with a structured
+        error (slot + cause) and keep the loop — and the other slots'
+        traffic — alive."""
+        fault = EngineFault(slot, cause)
+        now = time.perf_counter()
+        for handle, _, _, _ in spans:
+            handle._fail(fault, now)
+        logger.exception(
+            "engine batch for slot %r failed; %d request(s) "
+            "failed with EngineFault", slot, len(spans),
+        )
+        return rows
 
     def drain_slot(self, slot: str) -> None:
         """Serve every queued row for ``slot`` (the sync flush body and
@@ -333,10 +347,10 @@ class Scheduler:
                     # so cross-thread wakes/cancellations get a turn
                     await asyncio.sleep(0)
                     continue
+                due_in = self._next_due_in(now)
                 try:
-                    await asyncio.wait_for(
-                        self._wake.wait(), self._next_due_in(now)
-                    )
+                    with TraceAnnotation(WAIT):
+                        await asyncio.wait_for(self._wake.wait(), due_in)
                 except (asyncio.TimeoutError, TimeoutError):
                     pass
                 self._wake.clear()

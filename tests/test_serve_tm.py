@@ -183,6 +183,23 @@ def test_metrics_summary():
     assert s["request_latency_us"]["p50"] > 0
 
 
+def test_request_latency_summary_reads_the_lane_lists():
+    """``request_latency_us`` is over every completed request, read from
+    the per-lane latencies (one list per request, not two)."""
+    from repro.serve_tm import ServeMetrics
+
+    m = ServeMetrics()
+    lat = {"critical": [0.001, 0.004, 0.002], "low": [0.010, 0.003]}
+    for lane, xs in lat.items():
+        for t in xs:
+            m.record_lane_completion(lane, 0.0, t)
+    every = np.concatenate([np.asarray(xs) for xs in lat.values()])
+    got = m.summary()["request_latency_us"]
+    for q in (50, 95, 99):
+        assert got[f"p{q}"] == pytest.approx(np.percentile(every, q) * 1e6)
+    assert not hasattr(m, "request_latency_s")
+
+
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_private_jit_cache_per_executor(backend):
     """Two live engines of the SAME backend must count compilations

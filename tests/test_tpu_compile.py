@@ -23,7 +23,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.tm import TMConfig
-from repro.kernels.tm_popcount.kernel import tm_popcount, tm_popcount_xla
+from repro.kernels.tm_popcount.kernel import (
+    kernel_blocks,
+    tm_popcount,
+    tm_popcount_xla,
+)
 from repro.kernels.tm_train import fused_train_batch
 
 # The envelope CapacityPlan.for_models negotiates for the two MNIST-scale
@@ -80,6 +84,33 @@ def test_tm_popcount_compiles_for_v5e(one_chip, point, planes):
     args = _popcount_args(one_chip, planes=planes, **point)
     compiled = jax.jit(tm_popcount).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the kernel, not XLA
+
+
+def test_tm_popcount_kernel_keeps_its_name_on_v5e(one_chip):
+    """The serving step's kernel op is named ``tm_popcount`` whatever jit
+    calls it, so a profiler trace finds it by that name."""
+    from repro.accel.engines import _popcount_engine_pallas
+
+    i_cap, m_cap, f_cap, words = (DEFAULT[k] for k in
+                                  ("i_cap", "m_cap", "f_cap", "words"))
+    bi, bw = kernel_blocks(i_cap, words)
+    i_pad = -(-i_cap // bi) * bi  # kernel_operands' resident layout
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def serve_step(*args):
+        return _popcount_engine_pallas(
+            *args, weight_planes=1, block_instructions=bi,
+            block_words=bw, interpret=False)
+
+    args = (s((i_pad,), jnp.int32), s((i_pad,), jnp.int32),
+            s((i_pad, m_cap), jnp.uint32), s((i_pad, m_cap), jnp.uint32),
+            s((32 * words, f_cap), jnp.uint8))
+    text = jax.jit(serve_step).lower(*args).compile().as_text()
+    kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernel and all(
+        ln.lstrip().startswith("%tm_popcount") for ln in kernel)
 
 
 def test_tm_popcount_xla_compiles_for_v5e_mnist(one_chip):
