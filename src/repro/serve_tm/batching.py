@@ -22,7 +22,8 @@ whose deadline has already passed is never placed into a batch — it is
 ``result()`` (raises while pending), the blocking ``wait(timeout=)``, and
 the awaitable ``async_result()`` — the scheduler loop completes handles
 from its own thread and signals waiters on whatever event loop they
-registered from.
+registered from, with one callback per loop per batch
+(``signal_terminal``).
 """
 
 from __future__ import annotations
@@ -198,16 +199,6 @@ class RequestHandle:
                 await asyncio.wait_for(evt.wait(), timeout)
         return self.result()
 
-    def _signal_terminal(self) -> None:
-        with self._lock:
-            self._terminal_evt.set()
-            waiters, self._async_waiters = self._async_waiters, []
-        for loop, evt in waiters:
-            try:
-                loop.call_soon_threadsafe(evt.set)
-            except RuntimeError:
-                pass  # waiter's loop already closed; nothing to notify
-
     def _fill(
         self, lo: int, preds: np.ndarray, sums: Optional[np.ndarray] = None
     ) -> bool:
@@ -225,18 +216,54 @@ class RequestHandle:
             self.completed_at = time.perf_counter()
         return self.done
 
-    def _expire(self, now: float) -> None:
-        self.expired_at = now
-        self._signal_terminal()
-
     def _fail(self, exc: BaseException, now: Optional[float] = None) -> None:
         """Terminal failure: the batch body raised or the serving node
         died.  Waiters unblock and re-raise ``exc`` from ``result()``."""
+        if self._mark_failed(exc, now):
+            signal_terminal([self])
+
+    def _mark_failed(
+        self, exc: BaseException, now: Optional[float] = None
+    ) -> bool:
+        """Record a terminal failure; False when the handle was already
+        terminal (a served result is never overwritten).  Waiters are
+        woken by the caller (``signal_terminal``)."""
         if self._terminal_evt.is_set():
-            return  # already terminal — never overwrite a served result
+            return False
         self.error = exc
         self.failed_at = time.perf_counter() if now is None else now
-        self._signal_terminal()
+        return True
+
+
+def _set_all(events: List[asyncio.Event]) -> None:
+    for evt in events:
+        evt.set()
+
+
+def signal_terminal(handles: List[RequestHandle]) -> int:
+    """Mark ``handles`` terminal and wake their waiters, in order.
+
+    Each handle's ``threading.Event`` is set under its own lock.  Its
+    asyncio waiters are grouped by event loop, and each distinct loop
+    gets ONE ``call_soon_threadsafe`` callback that sets all of its
+    events: a batch of requests awaited from one loop costs one
+    cross-thread wake, not one per request.  A loop that has already
+    closed is skipped.  Returns the callbacks scheduled."""
+    by_loop: Dict[asyncio.AbstractEventLoop, List[asyncio.Event]] = {}
+    for handle in handles:
+        with handle._lock:
+            handle._terminal_evt.set()
+            waiters, handle._async_waiters = handle._async_waiters, []
+        for loop, evt in waiters:
+            by_loop.setdefault(loop, []).append(evt)
+    wakes = 0
+    for loop, events in by_loop.items():
+        try:
+            loop.call_soon_threadsafe(_set_all, events)
+        except RuntimeError:
+            continue  # waiter's loop already closed; nothing to notify
+        wakes += 1
+    return wakes
 
 
 class _Pending:
@@ -393,6 +420,7 @@ class Batcher:
                 out.fill(0)
             parts: List[np.ndarray] = []
             spans: List[Span] = []
+            shed: List[RequestHandle] = []
             rows = 0
             for priority in PRIORITIES:
                 lane = lanes[priority]
@@ -400,8 +428,8 @@ class Batcher:
                     key, seq, p = lane[0]
                     if key <= now:  # deadline passed: shed, never batch
                         heapq.heappop(lane)
-                        p.handle._expire(now)
-                        self._shed.append(p.handle)
+                        p.handle.expired_at = now
+                        shed.append(p.handle)
                         continue
                     take = min(p.remaining, self.batch_capacity - rows)
                     block = p.x[p.offset : p.offset + take]
@@ -418,6 +446,9 @@ class Batcher:
                         heapq.heappop(lane)
                 if rows >= self.batch_capacity:
                     break
+            if shed:
+                signal_terminal(shed)
+                self._shed.extend(shed)
             if not spans:  # everything queued had expired
                 empty = np.empty((0, n_features), np.uint8)
                 return (
@@ -440,10 +471,12 @@ class Batcher:
         preds: np.ndarray,
         sums: Optional[np.ndarray] = None,
         record: Optional[Callable[[List[RequestHandle]], None]] = None,
-    ) -> int:
+    ) -> Tuple[int, int]:
         """Scatter engine predictions (and, when given, the class-sum rows
-        the drift monitor taps) back into the request handles.  Returns how
-        many requests COMPLETED with this batch.
+        the drift monitor taps) back into the request handles.  Returns
+        how many requests COMPLETED with this batch, and how many
+        cross-thread wakes announced them (``signal_terminal``: one per
+        distinct event loop awaiting any of them).
 
         ``record`` sees the completed handles BEFORE their waiters wake,
         so a caller that reads metrics after ``result()`` returns always
@@ -456,6 +489,4 @@ class Batcher:
                 completed.append(handle)
         if record is not None:
             record(completed)
-        for handle in completed:
-            handle._signal_terminal()
-        return len(completed)
+        return len(completed), signal_terminal(completed)

@@ -14,6 +14,9 @@ of truth the golden-schema test, benchmarks/check_regression.py and the
 docs/accel.md table are all held to):
 
   batches, rows, requests_completed, swaps      int counters
+  completion_wakes                              cross-thread wakes of
+                                                asyncio waiters (one per
+                                                event loop per batch)
   fill_ratio                                    rows / padded engine rows
   throughput_dps                                rows / engine seconds
   engine_us / request_latency_us / swap_us      {p50, p95, p99}
@@ -67,6 +70,10 @@ class ServeMetrics:
         self.rows = 0            # real datapoints served
         self.padded_rows = 0     # engine rows incl. capacity padding
         self.requests_completed = 0
+        # cross-thread callbacks demux scheduled to wake asyncio waiters
+        # (one per event loop per batch): requests_completed / this is
+        # how many completions each wake announced
+        self.completion_wakes = 0
         self.swaps = 0
         self.recals = 0          # completed recalibration pipeline runs
         self.rollbacks = 0       # post-swap validation failures
@@ -97,6 +104,11 @@ class ServeMetrics:
         self.padded_rows += capacity
         self.engine_s.append(elapsed_s)
         self.requests_completed += completed
+
+    def record_completion_wakes(self, wakes: int) -> None:
+        """Cross-thread wakes one batch's demux scheduled; counted after
+        they were sent, so read it once the serving loop has stopped."""
+        self.completion_wakes += wakes
 
     def record_lane_completion(
         self,
@@ -232,6 +244,7 @@ class ServeMetrics:
             "batches": self.batches,
             "rows": self.rows,
             "requests_completed": self.requests_completed,
+            "completion_wakes": self.completion_wakes,
             "swaps": self.swaps,
             "fill_ratio": (
                 self.rows / self.padded_rows if self.padded_rows else 0.0

@@ -43,7 +43,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..accel.spans import BATCH, DEMUX, FORM, WAIT
-from .batching import Batcher, PRIORITIES, PRIORITY_RANK
+from .batching import Batcher, PRIORITIES, PRIORITY_RANK, signal_terminal
 
 logger = logging.getLogger(__name__)
 
@@ -259,13 +259,16 @@ class Scheduler:
                             missed=handle.missed_deadline,
                         )
 
-                with TraceAnnotation(DEMUX):
+                with TraceAnnotation(DEMUX) as demux:
                     try:
                         preds = np.argmax(sums, axis=1).astype(np.int32)
                     except Exception as cause:
                         return self._fail_batch(
                             slot, spans, cause, X.shape[0])
-                    Batcher.demux(spans, preds, sums, record=record)
+                    _, wakes = Batcher.demux(spans, preds, sums,
+                                             record=record)
+                    server.metrics.record_completion_wakes(wakes)
+                    demux.set_metadata(wakes=wakes)
                     server._check_no_recompile()
                 return X.shape[0]
 
@@ -277,8 +280,10 @@ class Scheduler:
         traffic — alive."""
         fault = EngineFault(slot, cause)
         now = time.perf_counter()
-        for handle, _, _, _ in spans:
-            handle._fail(fault, now)
+        signal_terminal([
+            handle for handle, _, _, _ in spans
+            if handle._mark_failed(fault, now)
+        ])
         logger.exception(
             "engine batch for slot %r failed; %d request(s) "
             "failed with EngineFault", slot, len(spans),

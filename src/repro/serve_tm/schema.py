@@ -26,6 +26,7 @@ SUMMARY_KEYS = (
     "batches",
     "rows",
     "requests_completed",
+    "completion_wakes",
     "swaps",
     "fill_ratio",
     "throughput_dps",

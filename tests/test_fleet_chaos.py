@@ -689,6 +689,7 @@ def test_pool_remove_and_stop_all_tolerate_dead_nodes():
         assert len(pool.warnings) == n_warnings + 1
     finally:
         pool.stop_all()
+        inner.stop()  # the killed wrapper cannot stop its server's loop
 
 
 # -- deprecations -------------------------------------------------------------

@@ -1,8 +1,10 @@
 """The serving path's profiler spans (``repro.accel.spans``), read back
 from a trace of a CPU run of the scheduler and the ``popcount`` engine
-(XLA twin): each batch body holds its phases once each, in order, and
-the scheduler's sleep lies outside every batch.  Also the stable name of
-each engine's jitted step."""
+(XLA twin): each batch body holds its phases once each, in order, the
+scheduler's sleep lies outside every batch, and ``tm.demux`` counts its
+wakes.  Also the stable name of each engine's jitted step."""
+
+import asyncio
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,17 @@ BURSTS = 6
 def _model(rng):
     cfg = TMConfig(n_classes=3, n_clauses=8, n_features=16)
     return encode(cfg, rng.random((3, 8, 32)) < 0.1)
+
+
+def _host_events(out):
+    """Per host trace line of the trace under ``out``, its ``tm.*``
+    events."""
+    data = ProfileData.from_file(str(next(out.rglob("*.xplane.pb"))))
+    return [
+        [ev for ev in ln.events if ev.name in spans.ALL]
+        for p in data.planes if p.name.startswith("/host:")
+        for ln in p.lines
+    ]
 
 
 @pytest.fixture(scope="module")
@@ -45,12 +58,10 @@ def lines(tmp_path_factory):
             jax.profiler.stop_trace()
     finally:
         acc.stop()
-    data = ProfileData.from_file(str(next(out.rglob("*.xplane.pb"))))
     found = [
-        sorted(((ev.name, ev.start_ns, ev.end_ns) for ev in ln.events
-                if ev.name in spans.ALL), key=lambda ev: (ev[1], -ev[2]))
-        for p in data.planes if p.name.startswith("/host:")
-        for ln in p.lines
+        sorted(((ev.name, ev.start_ns, ev.end_ns) for ev in ln),
+               key=lambda ev: (ev[1], -ev[2]))
+        for ln in _host_events(out)
     ]
     return [ln for ln in found if ln]
 
@@ -75,6 +86,35 @@ def test_the_scheduler_sleep_lies_outside_every_batch(lines):
     assert waits  # the loop slept between bursts
     for _, (_, s, e) in _batches(lines):
         assert not any(ws < e and we > s for _, ws, we in waits)
+
+
+def test_demux_span_counts_one_wake_per_asyncio_loop(tmp_path):
+    """A batch of one-row requests awaited from one asyncio loop reads
+    ``wakes=1`` on its ``tm.demux`` span."""
+    rng = np.random.default_rng(2)
+    model = _model(rng)
+    acc = Accelerator.for_models([model])
+    acc.load("m", acc.compile(model).to_bytes())
+    x = rng.integers(0, 2, (1, 16), dtype=np.uint8)
+    acc.infer("m", x)  # compile outside the trace
+
+    async def drive():
+        handles = [acc.submit("m", x) for _ in range(8)]
+        tasks = [asyncio.ensure_future(h.async_result(timeout=30.0))
+                 for h in handles]
+        while not all(h._async_waiters for h in handles):
+            await asyncio.sleep(0)
+        await asyncio.get_running_loop().run_in_executor(None, acc.flush)
+        return await asyncio.gather(*tasks)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert len(asyncio.run(drive())) == 8
+    finally:
+        jax.profiler.stop_trace()
+    demux = [dict(ev.stats) for ln in _host_events(tmp_path) for ev in ln
+             if ev.name == spans.DEMUX]
+    assert [d.get("wakes") for d in demux] == [1]
 
 
 def test_engine_steps_carry_their_names():

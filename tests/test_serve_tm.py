@@ -19,12 +19,15 @@ from repro.core.compress import encode
 from repro.serve_tm import (
     Batcher,
     DeadlineExceeded,
+    EngineFault,
     Overloaded,
     PRIORITIES,
     RequestHandle,
     ServeCapacity,
     TMServer,
 )
+from repro.serve_tm import batching
+from repro.serve_tm import scheduler as sched_mod
 
 BACKENDS = ("interp", "plan", "sharded", "popcount")
 
@@ -432,10 +435,153 @@ def test_demux_records_completions_before_waking_waiters():
         assert not any(h._terminal_evt.is_set() for h in completed)
 
     preds = np.ones(X.shape[0], np.int32)
-    assert Batcher.demux(spans, preds, record=record) == 1
+    assert Batcher.demux(spans, preds, record=record) == (1, 0)
     assert seen == [h_done] and h_done._terminal_evt.is_set()
     assert (h_done.result() == 1).all()
     assert not h_part.done and not h_part._terminal_evt.is_set()
+
+
+class _CountingLoop:
+    """Stands in for a waiter's event loop: counts the cross-thread
+    callbacks it is sent and runs each at once."""
+
+    def __init__(self):
+        self.calls = 0
+
+    def call_soon_threadsafe(self, callback, *args):
+        self.calls += 1
+        callback(*args)
+
+
+def _one_row_batch(n):
+    """``n`` one-row requests formed into one batch -> (handles, spans,
+    preds)."""
+    b = Batcher(32)
+    handles = [RequestHandle(i, "s", 1) for i in range(n)]
+    for h in handles:
+        b.enqueue(h, np.zeros((1, 8), np.uint8))
+    X, spans = b.next_batch("s")
+    return handles, spans, np.arange(X.shape[0], dtype=np.int32)
+
+
+def _await_on(handle, loop):
+    """Register an asyncio waiter for ``handle`` as if awaited on
+    ``loop``; -> its event."""
+    evt = asyncio.Event()
+    handle._async_waiters.append((loop, evt))
+    return evt
+
+
+def test_demux_wakes_one_asyncio_loop_once_per_batch():
+    """Eight one-row requests awaited from one asyncio loop, completed
+    by one batch on another thread, cost one cross-thread wake; every
+    awaiter still gets its own row."""
+    rng = np.random.default_rng(31)
+    cfg, acts, model = _random_model(rng, 4, 8, 32)
+    server = TMServer(CAP, backend="plan")
+    server.register("m", model)
+    xs = [rng.integers(0, 2, (1, 32)).astype(np.uint8) for _ in range(8)]
+
+    async def drive():
+        handles = [server.submit("m", x) for x in xs]
+        tasks = [asyncio.ensure_future(h.async_result(timeout=30.0))
+                 for h in handles]
+        while not all(h._async_waiters for h in handles):
+            await asyncio.sleep(0)
+        await asyncio.get_running_loop().run_in_executor(None, server.flush)
+        return await asyncio.gather(*tasks)
+
+    for preds, x in zip(asyncio.run(drive()), xs):
+        assert (preds == _oracle_sums(cfg, acts, x).argmax(1)).all()
+    s = server.metrics.summary()
+    assert s["batches"] == 1 and s["requests_completed"] == 8
+    assert s["completion_wakes"] == 1
+
+
+def test_demux_wakes_each_loop_once_in_completion_order():
+    """Handles awaited from two loops: exactly two callbacks, one per
+    loop, each setting its own loop's events in completion order."""
+    handles, spans, preds = _one_row_batch(6)
+    loops = (_CountingLoop(), _CountingLoop())
+    order = []
+    for i, h in enumerate(handles):
+        evt = _await_on(h, loops[i % 2])
+        evt.set = (lambda rid=h.rid: order.append(rid))
+    assert Batcher.demux(spans, preds) == (6, 2)
+    assert [lp.calls for lp in loops] == [1, 1]
+    assert order == [0, 2, 4, 1, 3, 5]
+    assert [int(h.result()[0]) for h in handles] == list(range(6))
+
+
+def test_demux_sync_waiter_wakes_without_a_callback():
+    """A handle waited on with the blocking ``wait()`` and no asyncio
+    waiter wakes from its threading event and adds no callback."""
+    handles, spans, preds = _one_row_batch(1)
+    got = []
+    waiter = threading.Thread(
+        target=lambda: got.append(handles[0].wait(timeout=30.0)))
+    waiter.start()
+    assert Batcher.demux(spans, preds) == (1, 0)
+    waiter.join(timeout=30.0)
+    assert not waiter.is_alive() and got and int(got[0][0]) == 0
+
+
+def test_demux_skips_a_closed_loop_and_wakes_the_others():
+    """A waiter whose loop has closed is skipped without raising and is
+    not counted; the other waiters of the batch still wake."""
+    handles, spans, preds = _one_row_batch(3)
+    closed = asyncio.new_event_loop()
+    closed.close()
+    live = _CountingLoop()
+    dead_evt = _await_on(handles[0], closed)
+    live_evts = [_await_on(h, live) for h in handles[1:]]
+    assert Batcher.demux(spans, preds) == (3, 1)
+    assert live.calls == 1 and all(e.is_set() for e in live_evts)
+    assert not dead_evt.is_set()
+    assert all(h._terminal_evt.is_set() for h in handles)
+
+
+def test_fail_batch_fails_every_handle_through_signal_terminal(monkeypatch):
+    """A raising batch body fails all of its handles with one
+    ``signal_terminal`` call; their async awaiters re-raise
+    ``EngineFault``."""
+    rng = np.random.default_rng(37)
+    _, _, model = _random_model(rng, 4, 8, 32)
+    server = TMServer(CAP, backend="plan")
+    server.register("m", model)
+    real = server.executor
+
+    class _Boom:
+        def __getattr__(self, name):
+            return getattr(real, name)
+
+        def class_sums(self, prog, xx):
+            raise RuntimeError("injected engine fault")
+
+    calls = []
+
+    def spy(handles):
+        calls.append(list(handles))
+        return batching.signal_terminal(handles)
+
+    monkeypatch.setattr(sched_mod, "signal_terminal", spy)
+    server.executor = _Boom()
+    xs = [rng.integers(0, 2, (1, 32)).astype(np.uint8) for _ in range(4)]
+
+    async def drive():
+        handles = [server.submit("m", x) for x in xs]
+        tasks = [asyncio.ensure_future(h.async_result(timeout=30.0))
+                 for h in handles]
+        while not all(h._async_waiters for h in handles):
+            await asyncio.sleep(0)
+        await asyncio.get_running_loop().run_in_executor(None, server.flush)
+        return handles, await asyncio.gather(*tasks, return_exceptions=True)
+
+    handles, results = asyncio.run(drive())
+    assert calls == [handles]
+    for h, r in zip(handles, results):
+        assert h.failed and isinstance(r, EngineFault) and r.slot == "m"
+        assert isinstance(r.cause, RuntimeError)
 
 
 def test_async_submit_admission_control_overload():
