@@ -1,7 +1,8 @@
-"""The control of a cell's ``correct``: the plain reference put in the
-program's place with one guarantee of the configuration broken (the last
-include of every clause dropped, as a truncated instruction stream would
-drop it), through the cell's own harness, traffic and comparison.  Its
+"""The control of a cell's ``correct``: the configuration's own plain
+reference put in the program's place with one guarantee of the
+configuration broken (its ``drop_last_include``: the last include of
+every clause dropped, as a truncated instruction stream would drop it),
+through the cell's own harness, traffic and comparison.  Its
 runs must come out not correct; beside them, sound runs of the program
 give the lower readings.
 
@@ -25,14 +26,20 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 
-def control_patch(engine, actions) -> None:
-    """Answer every batch with the reference of ``actions`` less the last
-    include of each clause, in the engine's place."""
-    from bench.configs import tm_reference as ref
+def control_patch(config: dict):
+    """A ``run_cell`` patch that answers every batch with the reference of
+    ``config`` on the model's truth less the last include of each clause,
+    in the engine's place."""
+    from bench.harness import load_reference
 
-    dropped = ref.drop_last_include(actions)
-    cap = engine.plan.batch_capacity
-    engine.class_sums = lambda prog, x: ref.class_sums(dropped, x, cap)
+    ref = load_reference(config["reference"])
+
+    def patch(engine, truth) -> None:
+        dropped = ref.drop_last_include(truth)
+        cap = engine.plan.batch_capacity
+        engine.class_sums = lambda prog, x: ref.class_sums(dropped, x, cap)
+
+    return patch
 
 
 def main(argv=None) -> int:
@@ -60,7 +67,7 @@ def main(argv=None) -> int:
                 cell, config, traffic, [], seed=seed, seconds=args.seconds,
                 trace=False, t_process=T_PROCESS,
                 require_tpu=bool(args.require_tpu),
-                patch=control_patch if side == "control" else None)
+                patch=control_patch(config) if side == "control" else None)
         except harness.NoChip as e:
             print(f"control: {e}; refusing to run", file=sys.stderr)
             return 2

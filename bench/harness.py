@@ -1,7 +1,9 @@
 """One run of one benchmark cell: set up, measure, check, report.
 
 Everything a cell needs is found by name: its configuration in
-``bench/configs/<config>.json``, its traffic mix in
+``bench/configs/<config>.json``, with the module that builds its model
+(``bench/configs/<model>.py``, ``models`` by default) and its plain
+reference, its traffic mix in
 ``bench/traffic/<traffic>.json`` with the generator module
 ``bench/traffic/<kind>.py``, and one reader ``bench/metrics/<metric>.py``
 per metric that ``BENCHMARK.json`` lists for the cell.  From the program
@@ -31,9 +33,6 @@ CACHE_DIR = ROOT / ".jax_cache"  # fixed: the path is part of the cache key
 TRACE_DIR = ROOT / ".bench_out" / "trace"
 WINDOW = "bench.window"  # the host span around the measured window
 SLOT = "cell"
-# the popcount kernel's device events: on the serving path it is the only
-# Pallas (Mosaic) kernel, and its trace events carry no name of their own
-KERNEL = 'custom_call_target="tpu_custom_call"'
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 CACHE_EVENT = "/jax/compilation_cache/cache_"  # + hits, misses
 
@@ -82,6 +81,22 @@ def load_generator(kind: str):
 def load_reference(name: str):
     """The plain reference module ``bench/configs/<name>.py``."""
     return importlib.import_module(f"bench.configs.{name}")
+
+
+def load_model(config: dict):
+    """The model module ``bench/configs/<config["model"]>.py``
+    (``models`` where the configuration names none); an unknown name is
+    an error that lists the modules there."""
+    name = config.get("model", "models")
+    try:
+        return importlib.import_module(f"bench.configs.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"bench.configs.{name}":
+            raise
+        known = sorted(p.stem for p in (BENCH / "configs").glob("*.py")
+                       if not p.stem.startswith("_"))
+        raise KeyError(f"no model module {name!r} in bench/configs; "
+                       f"known: {known}") from None
 
 
 # -- the device ----------------------------------------------------------
@@ -171,6 +186,7 @@ class Run:
     and counters, and with ``--trace 1`` the reduced trace."""
 
     config: dict
+    model: object  # bench.configs.models.BenchModel
     setup_s: float
     window_s: float
     records: object  # bench.traffic.common.Records
@@ -189,9 +205,9 @@ def _delta(after: dict, before: dict) -> dict:
     return {k: after[k] - before[k] for k in after}
 
 
-def check(records, pools, actions: np.ndarray, reference) -> Dict[str, dict]:
+def check(records, pools, truth, reference) -> Dict[str, dict]:
     """Every answer that came back against the plain reference of the
-    model's include actions: each row's class sums and prediction.
+    model's ``truth``: each row's class sums and prediction.
 
     -> ``{name: {"value": v, "max": limit}}`` or ``{"min": limit}``."""
     rows_wrong = rows_checked = 0
@@ -200,7 +216,7 @@ def check(records, pools, actions: np.ndarray, reference) -> Dict[str, dict]:
         sel = np.flatnonzero((records.pool == k) & ~records.failed)
         if not sel.size:
             continue
-        want = reference.class_sums(actions, pool.reshape(n * rows, f))
+        want = reference.class_sums(truth, pool.reshape(n * rows, f))
         want = want.reshape(n, rows, -1)[records.index[sel]]
         got = np.stack([records.sums[i] for i in sel])
         got_pred = np.stack([records.preds[i] for i in sel])
@@ -238,18 +254,15 @@ def run_cell(
     """One run -> (the result line as a dict, the ``Run`` it was read
     from).
 
-    ``patch(engine, actions)`` may replace the engine's ``class_sums``
+    ``patch(engine, truth)`` may replace the engine's ``class_sums``
     before any traffic: the control and the fault tests put their own
     answers in the program's place with it."""
     import jax
 
-    from bench.configs.models import include_actions
     from bench.traffic.common import TRAFFIC_STREAM
     from bench.work import peak
     from repro.accel import Accelerator
     from repro.compile_cache import enable_compile_cache
-    from repro.core import TMConfig
-    from repro.core.compress import encode
 
     phases = [("imports", time.perf_counter())]
     device = device_info(cell["chips"]) if require_tpu else {
@@ -261,19 +274,15 @@ def run_cell(
     phases.append(("backend", time.perf_counter()))
 
     with Watch() as watch:
-        actions = include_actions(config, seed)
-        cfg = TMConfig(n_classes=config["n_classes"],
-                       n_clauses=config["n_clauses"],
-                       n_features=config["n_features"])
-        model = encode(cfg, actions)
+        model = load_model(config).build(config, seed)
         gen = load_generator(traffic["kind"]).build(
-            traffic, actions, np.random.default_rng([seed, TRAFFIC_STREAM]))
+            traffic, model, np.random.default_rng([seed, TRAFFIC_STREAM]))
         phases.append(("model_and_traffic", time.perf_counter()))
 
-        acc = Accelerator.for_models([model])
+        acc = Accelerator.for_models([model.program_model])
         if patch is not None:
-            patch(acc.engine, actions)
-        acc.load(SLOT, acc.compile(model).to_bytes())
+            patch(acc.engine, model.truth)
+        acc.load(SLOT, acc.compile(model.program_model).to_bytes())
         acc.start()
         phases.append(("envelope_and_load", time.perf_counter()))
         try:
@@ -302,13 +311,14 @@ def run_cell(
     if trace:
         from bench.trace_reduce import find_trace, reduce_trace
 
-        reduced = reduce_trace(find_trace(TRACE_DIR), WINDOW, [KERNEL])
+        reduced = reduce_trace(find_trace(TRACE_DIR), WINDOW,
+                               model.kernels.values())
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
 
-    checks = check(records, gen.pools, actions,
+    checks = check(records, gen.pools, model.truth,
                    load_reference(config["reference"]))
-    run = Run(config=config, setup_s=setup_s,
+    run = Run(config=config, model=model, setup_s=setup_s,
               window_s=records.t_end - records.t_start, records=records,
               counters=counters, peak=pk, trace=reduced)
     values = {}

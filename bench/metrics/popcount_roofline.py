@@ -1,20 +1,18 @@
 """The popcount kernel's share of its roofline in the traced window: the
-least time the chip needs for the work served (``bench/work.py``, from
-the model and the rows, at the peaks of ``bench/peaks.json``) over the
-summed device time of the kernel's events."""
+least time the chip needs for the work served (the model's ``ops`` and
+``bytes``, ``bench/configs/``, at the peaks of ``bench/peaks.json``)
+over the summed device time of the events that match the model's
+``popcount`` kernel."""
 
 from bench import work
-from bench.harness import KERNEL
 
 
 def read(run):
-    t = run.trace
-    if t is None or run.peak is None or not t["kernel_s"].get(KERNEL):
+    t, pattern = run.trace, run.model.kernels.get("popcount")
+    if t is None or run.peak is None or not t["kernel_s"].get(pattern):
         return None
-    cfg, c = run.config, run.counters
-    ops = work.serve_ops(c["rows"], cfg["n_includes"],
-                         cfg["n_classes"] * cfg["n_clauses"])
-    nbytes = work.serve_bytes(c["rows"], c["batches"], cfg["n_includes"],
-                              cfg["n_features"], cfg["n_classes"])
-    pct, _ = work.roofline(ops, nbytes, t["kernel_s"][KERNEL], run.peak)
+    c = run.counters
+    pct, _ = work.roofline(run.model.ops(c["rows"]),
+                           run.model.bytes(c["rows"], c["batches"]),
+                           t["kernel_s"][pattern], run.peak)
     return pct

@@ -4,7 +4,8 @@ in flight in ``lane``, and send the next only when the last came back.
 Parameters (``bench/traffic/<mix>.json``): ``clients``, ``rows``,
 ``lane``, ``pool_requests`` (distinct requests drawn from the seed, sent
 in a seeded order and repeated when the run outlasts them),
-``satisfy_clauses`` (see ``common.make_rows``), ``warmup_seconds``.
+``satisfy_clauses`` (clauses of the model each row is set to satisfy,
+``BenchModel.rows``), ``warmup_seconds``.
 One thread runs every client as an asyncio task.
 """
 
@@ -16,19 +17,17 @@ import time
 
 import numpy as np
 
-from .common import WAIT_S, Outcomes, annotate, make_rows
+from .common import WAIT_S, Outcomes, annotate
 
 
 class Closed:
-    def __init__(self, params: dict, actions: np.ndarray,
-                 rng: np.random.Generator):
+    def __init__(self, params: dict, model, rng: np.random.Generator):
         self.clients = int(params["clients"])
         self.rows = int(params["rows"])
         self.lane = params["lane"]
         self.warmup_seconds = float(params["warmup_seconds"])
         n = int(params["pool_requests"])
-        x = make_rows(actions, n * self.rows, int(params["satisfy_clauses"]),
-                      rng)
+        x = model.rows(n * self.rows, int(params["satisfy_clauses"]), rng)
         self.pools = [x.reshape(n, self.rows, -1)]
         self._order = itertools.cycle(rng.permutation(n).tolist())
 
@@ -62,5 +61,5 @@ class Closed:
         return out.records(t_start, t_end)
 
 
-def build(params, actions, rng):
-    return Closed(params, actions, rng)
+def build(params, model, rng):
+    return Closed(params, model, rng)

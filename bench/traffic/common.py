@@ -1,5 +1,6 @@
-"""What every traffic generator shares: request rows drawn from the
-seed, and the record of what a run sent and what came back."""
+"""What every traffic generator shares: the record of what a run sent and
+what came back.  Request rows come from the model
+(``BenchModel.rows``, ``bench/configs/``)."""
 
 from __future__ import annotations
 
@@ -15,43 +16,11 @@ import numpy as np
 TRAFFIC_DIR = Path(__file__).resolve().parent
 TRAFFIC_STREAM = 2  # default_rng([seed, TRAFFIC_STREAM]) draws the traffic
 WAIT_S = 60.0  # an answer may come this long after the window closes
-PLANT_CHUNK = 8192  # rows per vectorised planting step
 
 
 def load_traffic(name: str) -> dict:
     """The traffic mix ``bench/traffic/<name>.json``."""
     return json.loads((TRAFFIC_DIR / f"{name}.json").read_text())
-
-
-def make_rows(actions: np.ndarray, n_rows: int, satisfy: int,
-              rng: np.random.Generator) -> np.ndarray:
-    """uint8[n_rows, F]: uniform random bits, then each row set to satisfy
-    ``satisfy`` clauses drawn at random from the model, as rows of a
-    model's own distribution make its clauses fire."""
-    m, c, l2 = actions.shape
-    x = rng.integers(0, 2, (n_rows, l2 // 2), dtype=np.uint8)
-    if satisfy <= 0:
-        return x
-    flat = actions.reshape(m * c, l2)
-    clauses = np.flatnonzero(flat.any(axis=1))
-    width = int(flat.sum(axis=1).max())
-    slots = np.zeros((clauses.size, width), np.int64)
-    valid = np.zeros((clauses.size, width), bool)
-    for q, clause in enumerate(clauses):
-        ks = np.flatnonzero(flat[clause])
-        slots[q, :ks.size], valid[q, :ks.size] = ks, True
-    pick = rng.integers(0, clauses.size, (n_rows, satisfy))
-    for lo in range(0, n_rows, PLANT_CHUNK):
-        p = pick[lo:lo + PLANT_CHUNK]
-        rows = np.broadcast_to(
-            np.arange(lo, lo + p.shape[0])[:, None, None],
-            (p.shape[0], satisfy, width),
-        )
-        ok = valid[p]
-        k = slots[p][ok]
-        # slot 2f is feature f, slot 2f+1 its negation
-        x[rows[ok], k >> 1] = 1 - (k & 1)
-    return x
 
 
 class Outcomes:

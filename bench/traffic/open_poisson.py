@@ -4,7 +4,7 @@ whether or not earlier requests came back.
 Parameters (``bench/traffic/<mix>.json``): ``rate_per_s``; ``mix``, a
 list of request classes, each with ``share`` (of requests), ``rows``,
 ``lane`` and ``pool_requests`` (distinct requests drawn from the seed);
-``satisfy_clauses`` (see ``common.make_rows``); ``warmup_seconds``.
+``satisfy_clauses`` (see ``closed.py``); ``warmup_seconds``.
 Each request is timed from when it was due; ``Records.lag`` holds how
 late the generator sent it.
 """
@@ -15,15 +15,13 @@ import time
 
 import numpy as np
 
-from .common import (WAIT_S, Outcomes, annotate, is_terminal, make_rows,
-                     wait_all)
+from .common import WAIT_S, Outcomes, annotate, is_terminal, wait_all
 
 LEAD_S = 0.005  # the first arrival is due this long after the run starts
 
 
 class OpenPoisson:
-    def __init__(self, params: dict, actions: np.ndarray,
-                 rng: np.random.Generator):
+    def __init__(self, params: dict, model, rng: np.random.Generator):
         self.rate = float(params["rate_per_s"])
         self.warmup_seconds = float(params["warmup_seconds"])
         self.mix = params["mix"]
@@ -32,9 +30,8 @@ class OpenPoisson:
             raise ValueError(f"mix shares sum to {self.share[-1]}, not 1")
         satisfy = int(params["satisfy_clauses"])
         self.pools = [
-            make_rows(actions, int(c["pool_requests"]) * int(c["rows"]),
-                      satisfy, rng).reshape(int(c["pool_requests"]),
-                                            int(c["rows"]), -1)
+            model.rows(int(c["pool_requests"]) * int(c["rows"]), satisfy, rng)
+            .reshape(int(c["pool_requests"]), int(c["rows"]), -1)
             for c in self.mix
         ]
         self.rng = rng
@@ -91,5 +88,5 @@ def _collect(pending, out):
     return still
 
 
-def build(params, actions, rng):
-    return OpenPoisson(params, actions, rng)
+def build(params, model, rng):
+    return OpenPoisson(params, model, rng)

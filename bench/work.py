@@ -1,14 +1,11 @@
-"""The work a TM serving batch needs, counted from the model and the rows
-served, whatever implements it (never from a kernel's padded layout),
-and the chip's peaks it is measured against.
+"""The chip's peaks, and a kernel's share of its roofline.
 
-Per datapoint: one AND per include and one add per clause.  Per batch:
-the uint16 include stream is read once; per row, the packed literals
-(2F bits) come in and M int32 class sums go out.
+The work a batch needs comes from the configuration's model module
+(``BenchModel.ops`` and ``BenchModel.bytes``, ``bench/configs/``).
 
 The v5e publishes no peak for bitwise integer work; its int8 peak, the
 only integer peak it publishes, stands as the ceiling for the ANDs and
-adds counted here.
+adds the TM models count.
 """
 
 from __future__ import annotations
@@ -17,19 +14,6 @@ import json
 from pathlib import Path
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
-
-
-def serve_ops(rows: int, n_includes: int, n_clauses: int) -> int:
-    """Operations to answer ``rows`` datapoints; ``n_clauses`` counts the
-    clauses of every class."""
-    return rows * (n_includes + n_clauses)
-
-
-def serve_bytes(rows: int, batches: int, n_includes: int, n_features: int,
-                n_classes: int) -> float:
-    """Bytes moved to answer ``rows`` datapoints in ``batches`` calls."""
-    return (batches * 2 * n_includes
-            + rows * (2 * n_features / 8 + n_classes * 4))
 
 
 def peak(device_kind: str) -> dict:

@@ -40,7 +40,7 @@ def wrap(fault):
     """A patch that breaks the engine's answers with ``fault(sums,
     state)``, where the engine computed them."""
 
-    def patch(engine, actions):
+    def patch(engine, truth):
         real, state = engine.class_sums, {}
 
         def class_sums(prog, x):
@@ -72,7 +72,7 @@ CASES = {
     "closed_traced": (CLOSED, True, None),
     "open": (OPEN, False, None),
     "open_traced": (OPEN, True, None),
-    "control": (CLOSED, False, control.control_patch),
+    "control": (CLOSED, False, control.control_patch(CONFIG)),
     "fault_altered": (CLOSED, False, wrap(altered)),
     "fault_half_left_out": (CLOSED, False, wrap(half_left_out)),
     "fault_stale": (CLOSED, False, wrap(stale)),

@@ -4,9 +4,9 @@ seed, and the rows do what their parameters say."""
 import numpy as np
 import pytest
 
-from bench.configs.models import include_actions, load_config
+from bench.configs import models
+from bench.configs.models import include_actions, load_config, make_rows
 from bench.traffic import closed, open_poisson
-from bench.traffic.common import make_rows
 
 TINY = {"n_classes": 3, "n_clauses": 8, "n_features": 16, "n_includes": 40}
 
@@ -44,11 +44,11 @@ def test_rows_repeat_and_satisfy_planted_clauses():
 
 
 def _closed(seed):
-    acts = include_actions(dict(TINY, include_rule="literal"), 0)
+    model = models.build(dict(TINY, include_rule="literal"), 0)
     params = {"clients": 2, "rows": 4, "lane": "normal",
               "pool_requests": 16, "satisfy_clauses": 2,
               "warmup_seconds": 0.1}
-    return closed.build(params, acts, np.random.default_rng([seed, 2]))
+    return closed.build(params, model, np.random.default_rng([seed, 2]))
 
 
 def test_closed_schedule_repeats_for_a_seed():
@@ -59,14 +59,15 @@ def test_closed_schedule_repeats_for_a_seed():
 
 
 def _open(seed):
-    acts = include_actions(dict(TINY, include_rule="literal"), 0)
+    model = models.build(dict(TINY, include_rule="literal"), 0)
     params = {"rate_per_s": 500.0, "satisfy_clauses": 1,
               "warmup_seconds": 0.1,
               "mix": [{"share": 0.75, "rows": 1, "lane": "critical",
                        "pool_requests": 32},
                       {"share": 0.25, "rows": 4, "lane": "normal",
                        "pool_requests": 8}]}
-    return open_poisson.build(params, acts, np.random.default_rng([seed, 2]))
+    return open_poisson.build(params, model,
+                              np.random.default_rng([seed, 2]))
 
 
 def test_open_schedule_repeats_for_a_seed_and_keeps_its_rate():
@@ -82,10 +83,10 @@ def test_open_schedule_repeats_for_a_seed_and_keeps_its_rate():
 
 
 def test_open_mix_shares_must_sum_to_one():
-    acts = include_actions(dict(TINY, include_rule="literal"), 0)
+    model = models.build(dict(TINY, include_rule="literal"), 0)
     params = {"rate_per_s": 10.0, "satisfy_clauses": 0,
               "warmup_seconds": 0.1,
               "mix": [{"share": 0.5, "rows": 1, "lane": "critical",
                        "pool_requests": 2}]}
     with pytest.raises(ValueError, match="shares"):
-        open_poisson.build(params, acts, np.random.default_rng(0))
+        open_poisson.build(params, model, np.random.default_rng(0))

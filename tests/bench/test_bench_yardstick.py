@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from bench import harness, work
-from bench.configs import tm_reference
+from bench.configs import models, tm_reference
 from bench.trace_reduce import reduce_trace
 
 TRACE = Path(__file__).resolve().parent / "data" / "trace_small.xplane.pb"
@@ -17,11 +17,11 @@ TRACE = Path(__file__).resolve().parent / "data" / "trace_small.xplane.pb"
 
 def test_work_on_a_hand_counted_model():
     # 2 classes x 3 clauses, 5 includes, 4 features, 7 rows in 2 batches
-    assert work.serve_ops(7, n_includes=5, n_clauses=6) == 7 * 11
+    assert models.serve_ops(7, n_includes=5, n_clauses=6) == 7 * 11
     # per batch 2 x 5 bytes of stream; per row 8 literal bits = 1 byte in
     # and 2 x 4 bytes of sums out
-    assert work.serve_bytes(7, 2, n_includes=5, n_features=4,
-                            n_classes=2) == 20 + 7 * 9
+    assert models.serve_bytes(7, 2, n_includes=5, n_features=4,
+                              n_classes=2) == 20 + 7 * 9
 
 
 def test_roofline_names_its_bound():
@@ -72,7 +72,7 @@ def test_control_drops_exactly_the_last_include_of_each_clause():
 
 @pytest.fixture(scope="module")
 def reduced():
-    return reduce_trace(TRACE, harness.WINDOW, [harness.KERNEL])
+    return reduce_trace(TRACE, harness.WINDOW, [models.POPCOUNT])
 
 
 def _events():
@@ -97,9 +97,9 @@ def test_trace_window_devices_and_kernel(reduced):
     # ops on one core run one at a time: busy is their summed time
     assert reduced["busy_s"] == pytest.approx(
         sum(ev.duration_ns for ev in inside) / 1e9, rel=1e-6)
-    kernel = [ev for ev in inside if harness.KERNEL in ev.name]
+    kernel = [ev for ev in inside if models.POPCOUNT in ev.name]
     assert len(kernel) > 0
-    assert reduced["kernel_s"][harness.KERNEL] == pytest.approx(
+    assert reduced["kernel_s"][models.POPCOUNT] == pytest.approx(
         sum(ev.duration_ns for ev in kernel) / 1e9)
     assert 0 < reduced["busy_s"] < reduced["window_s"]
 

@@ -14,6 +14,7 @@ from types import SimpleNamespace
 import pytest
 
 from bench import harness, span_reduce
+from bench.configs import models
 from bench.trace_reduce import reduce_trace
 
 HERE = Path(__file__).resolve().parent
@@ -110,7 +111,7 @@ def test_cpu_span_table_counts_each_phase_once_per_batch(cpu_runs, case):
 @pytest.fixture(scope="module")
 def chip():
     return (span_reduce.reduce_spans(TRACE, harness.WINDOW),
-            reduce_trace(TRACE, harness.WINDOW, [harness.KERNEL]))
+            reduce_trace(TRACE, harness.WINDOW, [models.POPCOUNT]))
 
 
 def test_chip_idle_split_sums_to_the_idle_time(chip):
@@ -152,11 +153,11 @@ def test_chip_kernel_and_step_keep_their_names(chip):
     from jax.profiler import ProfileData
 
     _, reduced = chip
-    assert reduced["kernel_s"][harness.KERNEL] > 0  # popcount_roofline's match
+    assert reduced["kernel_s"][models.POPCOUNT] > 0  # popcount_roofline's match
     events = [(ln.name, ev.name) for p in ProfileData.from_file(
         str(TRACE)).planes for ln in p.lines for ev in ln.events]
     kernel = [n for line, n in events
-              if line == "XLA Ops" and harness.KERNEL in n]
+              if line == "XLA Ops" and models.POPCOUNT in n]
     assert kernel and all(n.startswith("%tm_popcount.") for n in kernel)
     modules = {n.split("(")[0] for line, n in events if line == "XLA Modules"}
     assert modules == {"jit_tm_popcount_step"}
