@@ -20,6 +20,16 @@ instructions per ``uint32`` chunk), feature memory to 16 (the uint16
 stream protocol ships features 16 per word and the 2F interleaved
 literal rows pack into whole ``uint32`` words), batch in 32-datapoint
 bit-packed words by construction.
+
+Convolutional TMs (``CompressedModel.patch``) split the input in two:
+``input_capacity`` is the staged row (the image's bits, the Feature
+Memory the patch unit reads windows out of) and ``feature_capacity`` the
+features of one patch (the literal slots a clause addresses);
+``position_capacity`` is the patch-memory depth, the positions a clause is
+evaluated at.  A plain TM stages its features and has one position, so a
+plan that leaves ``input_capacity`` at 0 stages ``feature_capacity`` bits
+and ``position_capacity`` defaults to 1: the envelope of every plain TM
+is what it always was.
 """
 
 from __future__ import annotations
@@ -40,6 +50,8 @@ QUANTA: Dict[str, int] = {
     "include_capacity": 1,
     "batch_words": 1,
     "weight_planes": 1,
+    "position_capacity": 1,
+    "input_capacity": 16,
 }
 
 # the knobs recalibration can grow (include streams get denser, clauses
@@ -112,6 +124,10 @@ def model_requirements(
         # weightless models, so legacy populations negotiate exactly the
         # envelope they always did
         req["weight_planes"] = model.weight_planes
+    if "position_capacity" in wanted:
+        req["position_capacity"] = model.n_positions
+    if "input_capacity" in wanted:
+        req["input_capacity"] = model.input_width
     return req
 
 
@@ -129,14 +145,21 @@ class CapacityPlan:
     include_capacity: int = 32         # includes per clause (clause-major)
     batch_words: int = 4               # 32 datapoints per bit-packed word
     weight_planes: int = 1             # clause-weight bitplanes (repro.prune)
+    position_capacity: int = 1         # patch positions (convolutional TM)
+    input_capacity: int = 0            # staged bits a row; 0: feature_capacity
 
     KNOBS = (
         "instruction_capacity", "feature_capacity", "class_capacity",
         "clause_capacity", "include_capacity", "batch_words",
-        "weight_planes",
+        "weight_planes", "position_capacity", "input_capacity",
     )
 
     def __post_init__(self):
+        if isinstance(self.input_capacity, (int, np.integer)) and (
+            self.input_capacity == 0
+        ):
+            # a plain TM stages exactly its features
+            object.__setattr__(self, "input_capacity", self.feature_capacity)
         for knob in self.KNOBS:
             v = getattr(self, knob)
             if not isinstance(v, (int, np.integer)) or v < 1:

@@ -16,9 +16,9 @@ contract:
                             program — the zero-resynthesis property; must
                             stay 1 across model swaps.
   ``staging``               the engine's preallocated
-                            [batch_capacity, feature_capacity] uint8
-                            feature staging array; the batcher packs
-                            request rows straight into it
+                            [batch_capacity, input_capacity] uint8
+                            staging array; the batcher packs request
+                            rows straight into it
                             (``Batcher.next_batch(out=...)``).
 
 Engines self-describe through capability flags set by the
@@ -35,7 +35,11 @@ Engines self-describe through capability flags set by the
   ``validated_knobs``       which ``CapacityPlan`` buffers the engine's
                             layout actually instantiates — ``program``
                             validates exactly those (e.g. the clause
-                            tables bound only the sharded engine).
+                            tables bound only the sharded engine);
+  ``evaluates_positions``   the engine evaluates clauses at patch
+                            positions (a convolutional TM, the
+                            ``position_capacity`` knob); the others
+                            refuse a model with more than one position.
 
 Construction is uniform: ``make_engine(name, plan, **options)`` — mesh
 and implementation knobs are per-engine options, not special-cased
@@ -202,6 +206,9 @@ class EngineBase:
     # decodes the stream exactly once and shares it between validation
     # and _program (a swap must not pay repeated host-side stream walks)
     needs_decoded_plan = False
+    # whether the layout ORs clause outputs over patch positions; an engine
+    # that does not would serve a convolutional TM as a plain one
+    evaluates_positions = False
 
     def __init__(self, plan: CapacityPlan):
         self.plan = plan
@@ -244,7 +251,17 @@ class EngineBase:
     def validate_model(self, model, decoded=None) -> None:
         """Raise ``CapacityExceeded`` when ``model`` doesn't fit this
         engine's buffers (what ``Accelerator.compile`` gates on — the
-        exact check the load path will repeat)."""
+        exact check the load path will repeat).  An engine that evaluates
+        no positions refuses a convolutional TM (``ValueError``)."""
+        if model.n_positions > 1 and not self.evaluates_positions:
+            able = sorted(n for n, c in ENGINES.items()
+                          if c.evaluates_positions)
+            raise ValueError(
+                f"engine {self.name!r} evaluates each clause at one "
+                f"position, and the model is a convolutional TM with "
+                f"{model.n_positions} patch positions; serve it on an "
+                f"engine that ORs clauses over positions: {able}"
+            )
         bad = self.model_violations(model, decoded)
         if bad:
             raise CapacityExceeded(*bad[0])
@@ -277,20 +294,26 @@ class EngineBase:
 
     @property
     def staging(self) -> np.ndarray:
-        """The engine's preallocated [batch_capacity, feature_capacity]
-        uint8 feature staging array.  The batcher packs request rows
-        straight into it (``Batcher.next_batch(out=...)``) and the engines
-        consume it as their one fixed operand shape — no per-flush host
-        allocation."""
+        """The engine's preallocated [batch_capacity, input_capacity]
+        uint8 staging array (a plain TM's features, a convolutional TM's
+        images).  The batcher packs request rows straight into it
+        (``Batcher.next_batch(out=...)``) and the engines consume it as
+        their one fixed operand shape — no per-flush host allocation."""
         if self._staging is None:
             p = self.plan
             self._staging = np.zeros(
-                (p.batch_capacity, p.feature_capacity), np.uint8
+                (p.batch_capacity, p.input_capacity), np.uint8
             )
         return self._staging
 
+    def _pad_features(self, x: np.ndarray) -> np.ndarray:
+        """``_pad_x`` cut to the ``feature_capacity`` columns a plain TM's
+        literals come from (the whole staging array unless the plan
+        stages wider inputs for a convolutional TM)."""
+        return self._pad_x(x)[:, : self.plan.feature_capacity]
+
     def _pad_x(self, x: np.ndarray) -> np.ndarray:
-        """{0,1}[B, F] -> the staging array (zero-padded to capacity).
+        """{0,1}[B, X] -> the staging array (zero-padded to capacity).
 
         When ``x`` is already a view of ``self.staging`` (the batcher
         packed it there), it is returned as-is — zero copies."""
@@ -300,9 +323,9 @@ class EngineBase:
             raise CapacityExceeded(
                 "batch_words", -(-B // 32), p.batch_words, "batch"
             )
-        if F > p.feature_capacity:
+        if F > p.input_capacity:
             raise CapacityExceeded(
-                "feature_capacity", F, p.feature_capacity, "n_features"
+                "input_capacity", F, p.input_capacity, "input width"
             )
         st = self.staging
         if np.shares_memory(x, st):

@@ -20,6 +20,8 @@ tests/test_serve_tm.py and tests/test_accel.py):
     against per-class polarity-bank selection bitplanes.  Pallas kernel on
     TPU, the bit-exact pure-XLA twin elsewhere (``implementation``
     option); donates its per-call staging copy (``supports_donation``).
+    The only engine that evaluates patch positions (a convolutional TM):
+    the others refuse such a model (``EngineBase.validate_model``).
 
 Every engine instance owns a PRIVATE jit cache (a fresh closure over the
 underlying function), so ``compile_cache_size()`` counts only this
@@ -46,7 +48,12 @@ from jax.profiler import TraceAnnotation
 from ..configs.base import _pad_to
 from ..core.compress import CompressedModel, decode_to_plan
 from ..core.interp import interpret_stream, pack_features, pad_plan, plan_class_sums
-from ..core.tm import literals, pack_literals
+from ..core.tm import (
+    PatchGeometry,
+    literals,
+    pack_literals,
+    pack_patch_literals,
+)
 from ..dist.sharding import _axis_sizes, make_mesh
 from ..dist.tm_sharded import (
     TMShardedConfig,
@@ -60,7 +67,7 @@ from ..kernels.tm_popcount.kernel import (
     tm_popcount_resident,
     tm_popcount_xla,
 )
-from ..kernels.tm_popcount.ops import plan_to_popcount_operands
+from ..kernels.tm_popcount.ops import patch_operands, plan_to_popcount_operands
 from .capacity import CapacityExceeded, CapacityPlan
 from .engine import EngineBase, _private_jit, register_engine
 from .spans import D2H, H2D, LAUNCH
@@ -72,6 +79,7 @@ class InterpEngine(EngineBase):
 
     validated_knobs = (
         "instruction_capacity", "feature_capacity", "class_capacity",
+        "input_capacity",
     )
 
     def __init__(self, plan: CapacityPlan):
@@ -106,7 +114,8 @@ class InterpEngine(EngineBase):
         p = self.plan
         B = x.shape[0]
         packed = pack_features(
-            jnp.asarray(self._pad_x(x)), p.feature_capacity, p.batch_words
+            jnp.asarray(self._pad_features(x)), p.feature_capacity,
+            p.batch_words,
         )
         sums = self._fn(
             prog["imem"], prog["n_inst"], packed, jnp.int32(B), prog["wmem"],
@@ -127,7 +136,7 @@ class PlanEngine(EngineBase):
     # boundary EXTENDs never materialize in the decoded plan
     validated_knobs = (
         "instruction_capacity", "feature_capacity", "class_capacity",
-        "clause_capacity",
+        "clause_capacity", "input_capacity",
     )
     instruction_metric = "includes"
     needs_decoded_plan = True
@@ -164,7 +173,7 @@ class PlanEngine(EngineBase):
     def class_sums(self, prog: Dict[str, Any], x: np.ndarray) -> np.ndarray:
         p = self.plan
         B = x.shape[0]
-        lits = literals(jnp.asarray(self._pad_x(x)))  # [B_cap, 2*F_cap]
+        lits = literals(jnp.asarray(self._pad_features(x)))  # [B_cap, 2*F_cap]
         sums = self._fn(
             prog["li"], prog["ci"], prog["cc"], prog["cp"], lits,
             n_clause_cap=p.clause_total_capacity, m_cap=p.class_capacity,
@@ -172,20 +181,30 @@ class PlanEngine(EngineBase):
         return np.asarray(sums)[:B, : prog["n_classes"]]
 
 
-def _popcount_engine_xla(lit_idx, last, mask_pos, mask_neg, x_staged):
-    """Staged features -> packed interleaved literals -> popcount sums."""
+def _packed_literals(x_staged, patch):
+    """Staged rows -> the kernel's packed literal panel: ``[2F, W]`` of a
+    plain TM's features, or with the program's patch operands
+    (``sources``, ``valid``) ``[2F, P, W]`` of every patch of the staged
+    images, formed on the device inside the same step."""
+    if not patch:
+        return pack_literals(x_staged)
+    return pack_patch_literals(x_staged, *patch)
+
+
+def _popcount_engine_xla(lit_idx, last, mask_pos, mask_neg, x_staged, *patch):
+    """Staged rows -> packed interleaved literals -> popcount sums."""
     return tm_popcount_xla.__wrapped__(
-        lit_idx, last, mask_pos, mask_neg, pack_literals(x_staged)
+        lit_idx, last, mask_pos, mask_neg, _packed_literals(x_staged, patch)
     )
 
 
 def _popcount_engine_pallas(
-    lit_idx, last, mask_pos, mask_neg, x_staged,
-    *, weight_planes, block_instructions, block_words, interpret,
+    lit_idx, last, mask_pos, mask_neg, x_staged, *patch,
+    weight_planes, block_instructions, block_words, interpret,
 ):
     """The kernel on the program's resident layout (``kernel_operands``)."""
     sums = tm_popcount_resident.__wrapped__(
-        lit_idx, last, mask_pos, mask_neg, pack_literals(x_staged),
+        lit_idx, last, mask_pos, mask_neg, _packed_literals(x_staged, patch),
         block_instructions=block_instructions, block_words=block_words,
         interpret=interpret,
     )
@@ -202,14 +221,23 @@ class PopcountEngine(EngineBase):
     ONCE at ``program()`` (``jax.device_put``); each engine call ships only
     the staging block, donated to XLA so the feature buffer is recycled
     across flushes rather than accumulating.
+
+    A plan with ``position_capacity > 1`` stages images and evaluates
+    clauses at patch positions: the program also carries the geometry's
+    patch operands (``kernels.tm_popcount.ops.patch_operands``), the step
+    forms the patch literals on the device and the kernel ORs clause
+    outputs over positions.  A plain TM in such a plan is a 1 x F image
+    under a 1 x F window.  With one position the step is the plain one.
     """
 
     validated_knobs = (
         "instruction_capacity", "feature_capacity", "class_capacity",
         "weight_planes",  # the selection-bank depth is a compiled shape
+        "position_capacity", "input_capacity",
     )
     instruction_metric = "includes"  # operand vectors hold includes only
     needs_decoded_plan = True
+    evaluates_positions = True
 
     def __init__(
         self,
@@ -238,7 +266,8 @@ class PopcountEngine(EngineBase):
                     f"implementation='xla'"
                 )
             bi, bw = kernel_blocks(
-                plan.instruction_capacity, plan.batch_words
+                plan.instruction_capacity, plan.batch_words,
+                positions=plan.position_capacity,
             )
             self._block_instructions = bi
             engine = functools.partial(
@@ -266,6 +295,14 @@ class PopcountEngine(EngineBase):
             lit_idx, last, mask_pos, mask_neg = kernel_operands(
                 lit_idx, last, mask_pos, mask_neg, self._block_instructions
             )
+        patch = ()
+        if p.position_capacity > 1:
+            geom = model.patch or PatchGeometry(
+                1, model.n_features, 1, model.n_features)
+            patch = tuple(jax.device_put(a) for a in patch_operands(
+                geom, p.input_capacity, p.feature_capacity,
+                p.position_capacity,
+            ))
         # the reprogram is pure data movement: resident on-device until the
         # next swap, never retraced (fixed capacity shapes)
         return {
@@ -273,6 +310,7 @@ class PopcountEngine(EngineBase):
             "last": jax.device_put(last),
             "mask_pos": jax.device_put(mask_pos),
             "mask_neg": jax.device_put(mask_neg),
+            "patch": patch,
             "n_classes": model.n_classes,
             "n_features": model.n_features,
         }
@@ -285,7 +323,7 @@ class PopcountEngine(EngineBase):
         with TraceAnnotation(LAUNCH):
             sums = self._dispatch(
                 prog["lit_idx"], prog["last"],
-                prog["mask_pos"], prog["mask_neg"], staged,
+                prog["mask_pos"], prog["mask_neg"], staged, *prog["patch"],
             )
         with TraceAnnotation(D2H):
             return np.asarray(sums)[: prog["n_classes"], :B].T
@@ -302,7 +340,7 @@ class ShardedEngine(EngineBase):
 
     validated_knobs = (
         "feature_capacity", "class_capacity",
-        "clause_capacity", "include_capacity",
+        "clause_capacity", "include_capacity", "input_capacity",
     )
     needs_decoded_plan = True
 
@@ -345,7 +383,7 @@ class ShardedEngine(EngineBase):
         p = self.plan
         B = x.shape[0]
         lits = np.asarray(
-            literals(jnp.asarray(self._pad_x(x), bool))
+            literals(jnp.asarray(self._pad_features(x), bool))
         ).astype(np.int8)  # [B_cap, 2*F_cap]
         lits1 = np.concatenate(
             [lits, np.ones((p.batch_capacity, 1), np.int8)], axis=1

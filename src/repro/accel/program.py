@@ -16,7 +16,7 @@ a live accelerator with no shared process state:
 Layout (all little-endian):
 
     header   4s  magic  b"TMPG"
-             H   format version (1 or 2)
+             H   format version (1, 2 or 3)
              H   reserved (0)
              I   payload length in bytes
              I   CRC-32 of the payload
@@ -30,13 +30,24 @@ Layout (all little-endian):
              I   n_weights (per-clause weight count; 0 = weightless)
              H*  the instruction stream, n_instructions uint16 words
              H*  the clause-weight vector, n_weights uint16 words
+    v3       9I  capacity stamp (v2's seven + position_capacity,
+    payload      input_capacity)
+             4I  model dims (as v1)
+             I   n_weights (as v2)
+             4I  patch geometry (image_h, image_w, window_h, window_w);
+                 all 0 for a plain TM
+             H*  the instruction stream, then the clause weights (as v2)
 
 Version policy (repro.prune weighted clauses): a weightless model whose
 envelope has no weight planes beyond the implicit one serializes as v1 —
 BYTE-IDENTICAL to every pre-prune artifact (the golden-fixture guarantee).
 Weighted models (or plans provisioning ``weight_planes > 1``) emit v2.
 ``from_bytes`` loads both; the CRC covers the weight vector, so corrupted
-weight bytes are refused exactly like corrupted instructions.
+weight bytes are refused exactly like corrupted instructions.  A
+convolutional TM (``CompressedModel.patch``), or an envelope with patch
+positions or an input wider than its features, emits v3, whose geometry
+section the CRC covers too; plain models never do, so their v1/v2 bytes
+are unchanged.
 
 ``from_bytes`` refuses truncated blobs, wrong magic, future format
 versions and checksum mismatches with specific errors — a corrupted
@@ -53,10 +64,11 @@ from typing import Optional
 import numpy as np
 
 from ..core.compress import CompressedModel
+from ..core.tm import PatchGeometry
 from .capacity import CapacityPlan
 
 MAGIC = b"TMPG"
-FORMAT_VERSION = 2
+FORMAT_VERSION = 3
 
 # the v1 wire order is FROZEN: exactly the six knobs that existed when v1
 # shipped, regardless of what CapacityPlan.KNOBS grows to
@@ -65,12 +77,23 @@ _V1_KNOBS = (
     "clause_capacity", "include_capacity", "batch_words",
 )
 _V2_KNOBS = _V1_KNOBS + ("weight_planes",)
+_V3_KNOBS = _V2_KNOBS + ("position_capacity", "input_capacity")
+_GEOMETRY = ("image_h", "image_w", "window_h", "window_w")
 
 _HEADER = struct.Struct("<4sHHII")
 _CAPS = struct.Struct("<6I")
 _CAPS_V2 = struct.Struct("<7I")
+_CAPS_V3 = struct.Struct("<9I")
 _DIMS = struct.Struct("<4I")
 _NWEIGHTS = struct.Struct("<I")
+_PATCH = struct.Struct("<4I")
+# per version: the capacity stamp, its knobs, and whether the payload
+# carries a weight count and a geometry section
+_LAYOUT = {
+    1: (_CAPS, _V1_KNOBS, False, False),
+    2: (_CAPS_V2, _V2_KNOBS, True, False),
+    3: (_CAPS_V3, _V3_KNOBS, True, True),
+}
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -87,17 +110,31 @@ class TMProgram:
 
     def __post_init__(self):
         version = self.format_version
+        cap = self.capacity
+        convolutional = self.model.patch is not None or (
+            cap.position_capacity != 1
+            or cap.input_capacity != cap.feature_capacity
+        )
         if version is None:
             # emit the OLDEST format that covers the artifact: weightless
             # models in a plane-free envelope stay byte-identical v1
-            version = 1 if (
-                not self.model.weighted and self.capacity.weight_planes == 1
-            ) else 2
+            if convolutional:
+                version = 3
+            elif not self.model.weighted and cap.weight_planes == 1:
+                version = 1
+            else:
+                version = 2
             object.__setattr__(self, "format_version", version)
         if version == 1 and self.model.weighted:
             raise ValueError(
                 "TMProgram format v1 cannot carry clause weights; "
                 "serialize weighted models as v2"
+            )
+        if version < 3 and convolutional:
+            raise ValueError(
+                f"TMProgram format v{version} cannot carry a patch "
+                f"geometry, patch positions or an input wider than the "
+                f"features; serialize convolutional models as v3"
             )
 
     # -- identity ------------------------------------------------------------
@@ -110,6 +147,7 @@ class TMProgram:
             and self.model.n_classes == other.model.n_classes
             and self.model.n_clauses == other.model.n_clauses
             and self.model.n_features == other.model.n_features
+            and self.model.patch == other.model.patch
             and np.array_equal(self.model.instructions,
                                other.model.instructions)
         ):
@@ -137,10 +175,18 @@ class TMProgram:
         weights = b"" if m.clause_weights is None else (
             np.ascontiguousarray(m.clause_weights, dtype="<u2").tobytes()
         )
+        caps_s, knobs, _, has_patch = _LAYOUT[self.format_version]
+        geometry = b""
+        if has_patch:
+            geometry = _PATCH.pack(*(
+                (0,) * len(_GEOMETRY) if m.patch is None
+                else (getattr(m.patch, k) for k in _GEOMETRY)
+            ))
         return (
-            _CAPS_V2.pack(*(caps[k] for k in _V2_KNOBS))
+            caps_s.pack(*(caps[k] for k in knobs))
             + dims
             + _NWEIGHTS.pack(m.n_weights)
+            + geometry
             + stream
             + weights
         )
@@ -152,10 +198,10 @@ class TMProgram:
 
     @property
     def n_bytes(self) -> int:
-        if self.format_version == 1:
-            return (_HEADER.size + _CAPS.size + _DIMS.size
-                    + 2 * self.model.n_instructions)
-        return (_HEADER.size + _CAPS_V2.size + _DIMS.size + _NWEIGHTS.size
+        caps_s, _, has_weights, has_patch = _LAYOUT[self.format_version]
+        return (_HEADER.size + caps_s.size + _DIMS.size
+                + (_NWEIGHTS.size if has_weights else 0)
+                + (_PATCH.size if has_patch else 0)
                 + 2 * (self.model.n_instructions + self.model.n_weights))
 
     def to_bytes(self) -> bytes:
@@ -196,21 +242,35 @@ class TMProgram:
                 "TMProgram checksum mismatch — the artifact was corrupted "
                 "in transit; refusing to load it into a live accelerator"
             )
-        if version == 1:
-            caps_s, knobs, n_weights_s = _CAPS, _V1_KNOBS, 0
-        else:
-            caps_s, knobs, n_weights_s = _CAPS_V2, _V2_KNOBS, _NWEIGHTS.size
+        if version not in _LAYOUT:
+            raise ValueError(f"unknown TMProgram format version {version}")
+        caps_s, knobs, has_weights, has_patch = _LAYOUT[version]
+        n_weights_s = _NWEIGHTS.size if has_weights else 0
+        # the fixed sections before the stream
+        head = (caps_s.size + _DIMS.size + n_weights_s
+                + (_PATCH.size if has_patch else 0))
+        if payload_len < head:
+            raise ValueError(
+                f"truncated TMProgram artifact: a v{version} payload of "
+                f"{payload_len} bytes cannot hold its fixed sections"
+            )
         caps = caps_s.unpack_from(payload, 0)
         n_classes, n_clauses, n_features, n_instructions = _DIMS.unpack_from(
             payload, caps_s.size
         )
         n_weights = 0
-        if version >= 2:
+        if has_weights:
             (n_weights,) = _NWEIGHTS.unpack_from(
                 payload, caps_s.size + _DIMS.size
             )
-        expect = (caps_s.size + _DIMS.size + n_weights_s
-                  + 2 * (n_instructions + n_weights))
+        patch = None
+        if has_patch:
+            geometry = _PATCH.unpack_from(
+                payload, caps_s.size + _DIMS.size + n_weights_s
+            )
+            if any(geometry):
+                patch = PatchGeometry(*geometry)
+        expect = head + 2 * (n_instructions + n_weights)
         if payload_len != expect:
             # a CRC-consistent blob can still LIE about its own shape
             # (buggy producer): dims promising more words than present, or
@@ -222,15 +282,14 @@ class TMProgram:
                 f"({expect} payload bytes) but the payload carries "
                 f"{payload_len}"
             )
-        stream_off = caps_s.size + _DIMS.size + n_weights_s
         stream = np.frombuffer(
-            payload, dtype="<u2", count=n_instructions, offset=stream_off,
+            payload, dtype="<u2", count=n_instructions, offset=head,
         ).astype(np.uint16)
         weights = None
         if n_weights:
             weights = np.frombuffer(
                 payload, dtype="<u2", count=n_weights,
-                offset=stream_off + 2 * n_instructions,
+                offset=head + 2 * n_instructions,
             ).astype(np.uint16)
         return cls(
             capacity=CapacityPlan(**dict(zip(knobs, caps))),
@@ -240,6 +299,7 @@ class TMProgram:
                 n_clauses=n_clauses,
                 n_features=n_features,
                 clause_weights=weights,
+                patch=patch,
             ),
             format_version=version,
         )

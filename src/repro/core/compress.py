@@ -39,7 +39,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from .tm import TMConfig
+from .tm import PatchGeometry, TMConfig
 
 E_BIT = 15
 CC_BIT = 14
@@ -58,15 +58,27 @@ class CompressedModel:
     optional int vector with ONE entry per non-empty clause in stream
     emission order: the clause's vote is ``weight * pol`` instead of
     ``pol``.  ``None`` is the classic weightless model — every pre-prune
-    artifact and every v1 wire blob stays exactly what it was."""
+    artifact and every v1 wire blob stays exactly what it was.
+
+    ``patch`` is a Convolutional TM's geometry (``core.tm.PatchGeometry``):
+    the stream is unchanged, its literal slots index the features of one
+    patch (``n_features`` of them), and a request row is the image
+    (``input_width`` bits).  ``None`` is the plain TM."""
 
     instructions: np.ndarray  # uint16[I]
     n_classes: int
     n_clauses: int  # clauses per class (accumulator bound, Fig 4.6)
-    n_features: int  # Boolean features (feature-memory depth)
+    n_features: int  # Boolean features a clause sees (literal slots / 2)
     clause_weights: Optional[np.ndarray] = None  # uint16[Ncl'] emission order
+    patch: Optional[PatchGeometry] = None
 
     def __post_init__(self):
+        if self.patch is not None and self.n_features != self.patch.n_features:
+            raise ValueError(
+                f"a convolutional model's n_features is its patch's "
+                f"feature count {self.patch.n_features}, got "
+                f"{self.n_features}"
+            )
         if self.clause_weights is not None:
             w = np.asarray(self.clause_weights)
             if w.ndim != 1:
@@ -87,6 +99,16 @@ class CompressedModel:
     @property
     def n_instructions(self) -> int:
         return int(self.instructions.shape[0])
+
+    @property
+    def n_positions(self) -> int:
+        """Positions each clause is evaluated at (1 for the plain TM)."""
+        return 1 if self.patch is None else self.patch.n_positions
+
+    @property
+    def input_width(self) -> int:
+        """Bits of one request row: the image for a convolutional TM."""
+        return self.n_features if self.patch is None else self.patch.input_width
 
     @property
     def weighted(self) -> bool:
@@ -184,6 +206,7 @@ def encode(
         n_clauses=C,
         n_features=cfg.n_features,
         clause_weights=wvec,
+        patch=cfg.patch,
     )
 
 

@@ -37,6 +37,16 @@ VMEM-resident per batch block.  Every block is a whole array dim or whole
 Block shapes default to ``kernels.tuning.choose_blocks`` (a per-capacity
 synthesis-time choice, never a runtime recompile).
 
+Positions (the Convolutional TM, ``core.tm.PatchGeometry``): packed
+literals ``uint32[L2, P, W]`` carry a position axis.  Each literal row of
+the panel then holds the P x bw words of a word block position-major
+(word ``p * bw + w``, zero-padded to whole 128-lane tiles), so the sweep
+ANDs P x bw words per include, and before step 2 the emit buffer is
+OR-reduced over positions back to ``[bi, bw]`` (a clause fires on a
+datapoint iff it fires at some position).  Padded positions hold zero in
+both polarities, so they never fire.  With 2-D literals (one position)
+the kernel is the plain one, shape for shape.
+
 ``tm_popcount_xla`` is the same algorithm phrased as pure XLA ops (gather +
 segmented AND scan + bit transpose + popcount): the portable fast path the
 serving executors use on CPU/GPU, bit-exact with the Pallas kernel.
@@ -144,15 +154,44 @@ def popcount_reduce(
     return sums.transpose(0, 2, 1).reshape(m_cap, w * 32)
 
 
+def _or_positions(emitted: jax.Array, bw: int) -> jax.Array:
+    """``[bi, Q]`` position-major clause words (word ``p * bw + w``, Q a
+    whole number of 128-lane tiles, ``bw`` a power of two up to 128) ->
+    ``[bi, 128]`` whose lane ``w < bw`` holds the OR over positions of
+    word ``w``: the tiles are ORed together, then each lane with the lanes
+    a multiple of ``bw`` away (lane rolls)."""
+    fold = emitted[:, 0:128]
+    for t in range(1, emitted.shape[1] // 128):
+        fold = fold | emitted[:, 128 * t:128 * (t + 1)]
+    shift = 64
+    while shift >= bw:
+        fold = fold | pltpu.roll(fold, shift, 1)
+        shift //= 2
+    return fold
+
+
 def _tm_popcount_kernel(
     lit_idx_ref, last_ref,  # SMEM (scalar prefetch): int32[I_pad]
+    # (with stream_blocks: one int32[1, 1, bi] block per instruction block)
     mask_pos_ref, mask_neg_ref,  # VMEM uint32[bi, banks], one row per inst
-    lits_ref,  # VMEM uint32[L2, bw] — Feature Memory panel
-    out_ref,  # VMEM int32[banks, 32, bw] — the class-sum bank
-    acc_ref, emit_ref,  # VMEM scratch uint32[1, bw], uint32[bi, bw]
+    lits_ref,  # VMEM uint32[L2, bw] — Feature Memory panel ([L2, Q] with
+    # positions: P x bw words, position-major)
+    out_ref,  # VMEM int32[banks, 32, bw] — the class-sum bank ([.., 128])
+    acc_ref, emit_ref,  # VMEM scratch uint32[1, bw], uint32[bi, bw] (Q)
+    *,
+    positions_bw: int | None = None,  # bw when the words carry positions
+    stream_blocks: bool = False,  # the operand vectors come per block
 ):
-    bi, bw = emit_ref.shape
-    base = pl.program_id(1) * bi
+    bi = emit_ref.shape[0]
+    if stream_blocks:  # one SMEM block of the stream per instruction block
+
+        def operand(ref, t):
+            return ref[0, 0, t]
+    else:  # the whole stream is prefetched
+        base = pl.program_id(1) * bi
+
+        def operand(ref, t):
+            return ref[base + t]
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -161,23 +200,27 @@ def _tm_popcount_kernel(
 
     def body(t, acc):
         # Literal Select + Clause Compute: one packed AND per include
-        acc = acc & lits_ref[pl.ds(lit_idx_ref[base + t], 1), :]
-        emit = last_ref[base + t] == 1
+        acc = acc & lits_ref[pl.ds(operand(lit_idx_ref, t), 1), :]
+        emit = operand(last_ref, t) == 1
         emit_ref[pl.ds(t, 1), :] = jnp.where(emit, acc, jnp.uint32(0))
         return jnp.where(emit, jnp.uint32(ONES), acc)
 
     acc_ref[...] = jax.lax.fori_loop(0, bi, body, acc_ref[...])
+    emitted = emit_ref[...]
+    if positions_bw is not None:
+        emitted = _or_positions(emitted, positions_bw)
     # one bitplane transpose + popcount reduction per instruction block:
     # row 32c+b of ``planes`` holds, at bit j, datapoint b's output of
     # instruction 32c+j; each mask row selects the same 32 instructions
-    planes = bit_transpose32_rows(emit_ref[...], pltpu.roll)
+    planes = bit_transpose32_rows(emitted, pltpu.roll)
+    words = planes.shape[1]
     for m in range(out_ref.shape[0]):
         d = jax.lax.population_count(
             planes & mask_pos_ref[:, m : m + 1]
         ).astype(jnp.int32) - jax.lax.population_count(
             planes & mask_neg_ref[:, m : m + 1]
         ).astype(jnp.int32)
-        out_ref[m] += d.reshape(bi // 32, 32, bw).sum(axis=0)
+        out_ref[m] += d.reshape(bi // 32, 32, words).sum(axis=0)
 
 
 def kernel_blocks(
@@ -185,6 +228,7 @@ def kernel_blocks(
     n_words: int,
     block_instructions: int | None = None,
     block_words: int | None = None,
+    positions: int = 1,
 ) -> tuple[int, int]:
     """Validated ``(bi, bw)`` for the kernel at one capacity point.
 
@@ -193,7 +237,9 @@ def kernel_blocks(
     masks pack 32 instructions per word) and is clipped to the 32-aligned
     instruction depth; ``block_words`` is clipped to the word count and
     must then be all of it or a multiple of 128 (a block's last dim is a
-    whole array dim or whole 128-lane tiles)."""
+    whole array dim or whole 128-lane tiles).  With ``positions > 1`` the
+    word block is at most 128 and rounded up to a power of two, which the
+    OR over positions needs (the words are padded to it)."""
     if block_instructions is None or block_words is None:
         auto_bi, auto_bw = choose_blocks(i_cap, n_words)
         if block_instructions is None:
@@ -206,7 +252,9 @@ def kernel_blocks(
             f"{block_instructions}"
         )
     bw = min(block_words, n_words)
-    if bw <= 0 or (bw != n_words and bw % 128):
+    if positions > 1 and bw > 0:
+        bw = 1 << (min(bw, 128) - 1).bit_length()
+    elif bw <= 0 or (bw != n_words and bw % 128):
         raise ValueError(
             f"block_words must cover all {n_words} words or be a positive "
             f"multiple of 128, got {block_words}"
@@ -256,7 +304,7 @@ def tm_popcount(
     last_flag: jax.Array,  # int32[I_cap] 1 = last include of its clause
     mask_pos: jax.Array,  # uint32[m_cap, ceil(I_cap/32)] +clause selectors
     mask_neg: jax.Array,  # uint32[m_cap, ceil(I_cap/32)] -clause selectors
-    packed_lits: jax.Array,  # uint32[L2, W]
+    packed_lits: jax.Array,  # uint32[L2, W], or uint32[L2, P, W]
     *,
     block_instructions: int | None = None,
     block_words: int | None = None,
@@ -275,8 +323,8 @@ def tm_popcount(
     untouched and the whole path multiply-free.
     """
     bi, bw = kernel_blocks(
-        lit_idx.shape[0], packed_lits.shape[1], block_instructions,
-        block_words,
+        lit_idx.shape[0], packed_lits.shape[-1], block_instructions,
+        block_words, positions=lit_positions(packed_lits),
     )
     sums = tm_popcount_resident.__wrapped__(
         *kernel_operands(lit_idx, last_flag, mask_pos, mask_neg, bi),
@@ -287,6 +335,12 @@ def tm_popcount(
         p = mask_pos.shape[0]
         return sum_weight_planes(sums.reshape(p, -1, sums.shape[1]))
     return sums
+
+
+# the longest instruction stream whose operand vectors (two int32 each) are
+# prefetched whole into SMEM (1 MiB on a v5e), half of it; a longer one
+# comes one SMEM block per instruction block
+PREFETCH_INSTRUCTIONS = 65536
 
 
 @functools.partial(
@@ -307,43 +361,83 @@ def tm_popcount_resident(
     blocks from ``kernel_blocks`` -> int32[banks, W*32] class sums.
 
     ``lit_idx``/``last_flag`` ride in SMEM as scalar prefetch (read once
-    per instruction); each grid step reads a ``(bi, banks)`` row block of
-    the masks."""
+    per instruction), or, for a stream longer than
+    ``PREFETCH_INSTRUCTIONS``, one ``(1, 1, bi)`` SMEM block per
+    instruction block; each grid step reads a ``(bi, banks)`` row block
+    of the masks.  3-D ``packed_lits`` (``[L2, P, W]``) carry positions:
+    each word block's P x bw words are laid out position-major in whole
+    128-lane tiles, and the emit buffer is ORed over positions."""
     bi, bw = block_instructions, block_words
     i_pad, banks = mask_pos.shape
-    l2, w = packed_lits.shape
+    l2, w = packed_lits.shape[0], packed_lits.shape[-1]
     w_pad = -(-w // bw) * bw
+    n_blocks = w_pad // bw
+    options, n_prefetch, operand_specs = {}, 2, []
+    if i_pad > PREFETCH_INSTRUCTIONS:
+        options["stream_blocks"] = True
+        n_prefetch = 0
+        operand_specs = [
+            pl.BlockSpec((1, 1, bi), lambda j, i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+        ] * 2
+        lit_idx = lit_idx.reshape(i_pad // bi, 1, bi)
+        last_flag = last_flag.reshape(i_pad // bi, 1, bi)
+    out_words = bw
+    if packed_lits.ndim == 3:
+        p = packed_lits.shape[1]
+        q = -(-p * bw // 128) * 128  # a block's words, whole lane tiles
+        panel = jnp.pad(packed_lits, ((0, 0), (0, 0), (0, w_pad - w)))
+        panel = panel.reshape(l2, p, n_blocks, bw).transpose(0, 2, 1, 3)
+        panel = jnp.pad(panel.reshape(l2, n_blocks, p * bw),
+                        ((0, 0), (0, 0), (0, q - p * bw)))
+        panel = panel.reshape(l2, n_blocks * q)
+        options["positions_bw"] = bw
+        panel_words, out_words = q, 128
+    else:
+        panel = jnp.pad(packed_lits, ((0, 0), (0, w_pad - w)))
+        panel_words = bw
 
+    kernel = _tm_popcount_kernel
+    if options:
+        kernel = functools.partial(_tm_popcount_kernel, **options)
     out = pl.pallas_call(
-        _tm_popcount_kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(w_pad // bw, i_pad // bi),
-            in_specs=[
+            num_scalar_prefetch=n_prefetch,
+            grid=(n_blocks, i_pad // bi),
+            in_specs=operand_specs + [
                 pl.BlockSpec((bi, banks), lambda j, i, *_: (i, 0)),
                 pl.BlockSpec((bi, banks), lambda j, i, *_: (i, 0)),
-                pl.BlockSpec((l2, bw), lambda j, i, *_: (0, j)),
+                pl.BlockSpec((l2, panel_words), lambda j, i, *_: (0, j)),
             ],
             out_specs=pl.BlockSpec(
-                (banks, 32, bw), lambda j, i, *_: (0, 0, j)
+                (banks, 32, out_words), lambda j, i, *_: (0, 0, j)
             ),
             scratch_shapes=[
-                pltpu.VMEM((1, bw), jnp.uint32),  # packed clause accumulator
-                pltpu.VMEM((bi, bw), jnp.uint32),  # block emit buffer
+                # packed clause accumulator, block emit buffer
+                pltpu.VMEM((1, panel_words), jnp.uint32),
+                pltpu.VMEM((bi, panel_words), jnp.uint32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((banks, 32, w_pad), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(
+            (banks, 32, n_blocks * out_words), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
         name="tm_popcount",
-    )(
-        lit_idx, last_flag, mask_pos, mask_neg,
-        jnp.pad(packed_lits, ((0, 0), (0, w_pad - w))),
-    )
+    )(lit_idx, last_flag, mask_pos, mask_neg, panel)
+    if out_words != bw:  # word w of a block is in lane w of its 128
+        out = out.reshape(banks, 32, n_blocks, out_words)[..., :bw]
+        out = out.reshape(banks, 32, w_pad)
     # out[m, b, w] is datapoint 32w + b
     return out[:, :, :w].transpose(0, 2, 1).reshape(banks, w * 32)
+
+
+def lit_positions(packed_lits: jax.Array) -> int:
+    """Positions of a packed literal panel: ``[L2, P, W]`` carries P,
+    ``[L2, W]`` one."""
+    return packed_lits.shape[1] if packed_lits.ndim == 3 else 1
 
 
 def _segmented_and_scan(sel: jax.Array, start: jax.Array) -> jax.Array:
@@ -368,13 +462,14 @@ def tm_popcount_xla(
     last_flag: jax.Array,  # int32[I_cap]
     mask_pos: jax.Array,  # uint32[m_cap, ceil(I_cap/32)]
     mask_neg: jax.Array,  # uint32[m_cap, ceil(I_cap/32)]
-    packed_lits: jax.Array,  # uint32[L2, W]
+    packed_lits: jax.Array,  # uint32[L2, W], or uint32[L2, P, W]
 ) -> jax.Array:
     """The popcount bitplane algorithm as pure XLA -> int32[m_cap, W*32].
 
     Bit-exact with ``tm_popcount``; this is what the serving executors run
     off-TPU (Pallas interpret mode emulates the grid and is far slower than
-    native XLA on CPU).
+    native XLA on CPU).  With positions the clause words are ORed over
+    them before the popcount, as in the kernel.
     """
     i_cap = lit_idx.shape[0]
     i_pad = -(-i_cap // 32) * 32
@@ -385,9 +480,15 @@ def tm_popcount_xla(
     mask_pos = jnp.pad(mask_pos, lead + ((0, pad_chunks),))
     mask_neg = jnp.pad(mask_neg, lead + ((0, pad_chunks),))
 
-    sel = jnp.take(packed_lits, lit_idx, axis=0)  # [I, W] literal select
+    l2, w = packed_lits.shape[0], packed_lits.shape[-1]
+    panel = packed_lits.reshape(l2, -1)  # [L2, P*W]
+    sel = jnp.take(panel, lit_idx, axis=0)  # [I, P*W] literal select
     emit = last_flag == 1
     start = jnp.concatenate([jnp.ones((1,), bool), emit[:-1]])
     acc = _segmented_and_scan(sel, start)  # packed clause outputs
     emit_words = jnp.where(emit[:, None], acc, jnp.uint32(0))
+    if packed_lits.ndim == 3:  # OR over positions
+        emit_words = jax.lax.reduce(
+            emit_words.reshape(i_pad, -1, w), jnp.uint32(0),
+            jax.lax.bitwise_or, (1,))
     return popcount_reduce(emit_words, mask_pos, mask_neg)

@@ -145,6 +145,35 @@ def plan_to_popcount_operands(
     return lit_idx, last, mask_pos, mask_neg
 
 
+def patch_operands(
+    geom,  # core.tm.PatchGeometry
+    input_cap: int,
+    f_cap: int,
+    p_cap: int,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """A geometry -> the operands of ``core.tm.pack_patch_literals`` at a
+    capacity point: ``sources`` int32[f_cap, p_cap] into staged rows
+    ``input_cap`` wide (``input_cap`` is the constant 0, ``input_cap + 1``
+    the constant 1), and ``valid`` uint32[p_cap], all ones for the
+    geometry's positions.  Padded features read 0; padded positions are
+    masked by ``valid``.  Raises when the geometry exceeds the envelope."""
+    f, p, x = geom.n_features, geom.n_positions, geom.input_width
+    if f > f_cap or p > p_cap or x > input_cap:
+        raise ValueError(
+            f"patch geometry {geom} needs {f} features, {p} positions and "
+            f"{x} input bits; the envelope has {f_cap}, {p_cap} and "
+            f"{input_cap}"
+        )
+    src = geom.feature_sources()
+    src = np.where(src == x, input_cap, np.where(src == x + 1,
+                                                 input_cap + 1, src))
+    sources = np.full((f_cap, p_cap), input_cap, np.int32)
+    sources[:f, :p] = src
+    valid = np.zeros(p_cap, np.uint32)
+    valid[:p] = 0xFFFFFFFF
+    return sources, valid
+
+
 def tm_popcount_class_sums(
     plan: DecodedPlan,
     packed_lits: jax.Array,  # uint32[2F, W] (interleaved literal rows)

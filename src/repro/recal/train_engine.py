@@ -34,6 +34,12 @@ Engines self-describe through capability flags set by
 plus a per-class ``supports(cfg)`` hook for representation limits (the
 packed int8 layout holds at most 128 states per action).
 
+No train engine trains a Convolutional TM (``TMConfig.patch`` with more
+than one position: its feedback samples a position per clause, which
+none of them implements).  Each refuses such a config at construction,
+and ``select_train_engine`` finds none eligible, so the Fig-8 loop never
+trains one as a plain TM.
+
 Construction is uniform: ``make_train_engine(name, cfg, *, mesh=None,
 plan=None, **options)`` — mesh and implementation knobs are per-engine
 options, not special-cased branches (``RecalWorker`` no longer branches
@@ -119,6 +125,8 @@ def select_train_engine(
     fastest mesh-free engine that ``supports(cfg)`` wins (the packed
     engine bows out for configs outside its int8 state range).  Ties
     break lexicographically so selection is stable across processes."""
+    if cfg is not None:
+        refuse_convolutional(cfg)
     eligible = [
         c
         for c in TRAIN_ENGINES.values()
@@ -160,6 +168,18 @@ def make_train_engine(
     return cls(cfg, plan=plan, **options)
 
 
+def refuse_convolutional(cfg: TMConfig) -> None:
+    """Raise ``ValueError`` for a Convolutional TM with more than one
+    position: no train engine implements its position-sampling feedback,
+    and training it as a plain TM would publish a wrong model."""
+    if cfg.n_positions > 1:
+        raise ValueError(
+            f"no train engine trains a convolutional TM ({cfg.n_positions} "
+            f"patch positions): recalibration serves it but cannot "
+            f"retrain it"
+        )
+
+
 class TrainEngineBase:
     """Shared train-engine mechanics: batch-envelope validation and the
     canonical-representation identity hooks."""
@@ -169,6 +189,7 @@ class TrainEngineBase:
     priority = 0
 
     def __init__(self, cfg: TMConfig, *, plan=None):
+        refuse_convolutional(cfg)
         self.cfg = cfg
         self.plan = plan
 

@@ -394,10 +394,12 @@ class Batcher:
         spans).
 
         With ``out`` (an engine staging array of at least
-        ``[batch_capacity, F]``), request rows are packed straight into it
-        — no per-batch concatenate/allocation — the remainder of ``out``
-        is zeroed (the engines consume one fixed zero-padded operand
-        shape), and the returned block is the view ``out[:rows, :F]``.
+        ``[batch_capacity, F]``, F the slot's row width: a plain TM's
+        features, a convolutional TM's image bits), request rows are
+        packed straight into it — no per-batch concatenate/allocation —
+        the remainder of ``out`` is zeroed (the engines consume one fixed
+        zero-padded operand shape), and the returned block is the view
+        ``out[:rows, :F]``.
         """
         with self.lock:
             lanes = self._lanes.get(slot)
@@ -405,17 +407,17 @@ class Batcher:
                 raise ValueError(f"no pending requests for slot {slot!r}")
             if now is None:
                 now = time.perf_counter()
-            n_features = 0
+            width = 0  # the row width the slot's requests carry
             for p in PRIORITIES:
                 if lanes[p]:
-                    n_features = lanes[p][0][2].x.shape[1]
+                    width = lanes[p][0][2].x.shape[1]
                     break
             if out is not None:
                 if (out.shape[0] < self.batch_capacity
-                        or out.shape[1] < n_features):
+                        or out.shape[1] < width):
                     raise ValueError(
                         f"staging array {out.shape} too small for "
-                        f"{self.batch_capacity} rows x {n_features} features"
+                        f"{self.batch_capacity} rows x {width} bits"
                     )
                 out.fill(0)
             parts: List[np.ndarray] = []
@@ -436,7 +438,7 @@ class Batcher:
                     if out is None:
                         parts.append(block)
                     else:
-                        out[rows : rows + take, :n_features] = block
+                        out[rows : rows + take, :width] = block
                     if p.handle.dequeued_at is None:
                         p.handle.dequeued_at = now
                     spans.append((p.handle, rows, rows + take, p.offset))
@@ -450,12 +452,12 @@ class Batcher:
                 signal_terminal(shed)
                 self._shed.extend(shed)
             if not spans:  # everything queued had expired
-                empty = np.empty((0, n_features), np.uint8)
+                empty = np.empty((0, width), np.uint8)
                 return (
-                    out[:0, :n_features] if out is not None else empty
+                    out[:0, :width] if out is not None else empty
                 ), []
             if out is not None:
-                return out[:rows, :n_features], spans
+                return out[:rows, :width], spans
             return np.concatenate(parts, axis=0), spans
 
     def drain_shed(self) -> List[RequestHandle]:

@@ -236,7 +236,8 @@ class Scheduler:
                     self._record_shed()
                 if not spans:  # everything queued had already expired
                     return 0
-                batch.set_metadata(rows=X.shape[0], requests=len(spans))
+                batch.set_metadata(rows=X.shape[0], requests=len(spans),
+                                   positions=entry.model.n_positions)
                 t0 = time.perf_counter()
                 try:
                     sums = server.executor.class_sums(entry.program, X)

@@ -168,10 +168,10 @@ class TMServer:
             x = x[None, :]
         if x.ndim != 2 or x.shape[0] == 0:
             raise ValueError(f"expected {{0,1}}[b, F] features, got {x.shape}")
-        if x.shape[1] != entry.n_features:
+        if x.shape[1] != entry.model.input_width:
             raise ValueError(
                 f"request has {x.shape[1]} features; slot {slot!r} v"
-                f"{entry.version} expects {entry.n_features}"
+                f"{entry.version} expects {entry.model.input_width}"
             )
         if x.max(initial=0) > 1:
             raise ValueError("features must be Boolean {0,1}")

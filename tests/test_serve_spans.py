@@ -117,6 +117,35 @@ def test_demux_span_counts_one_wake_per_asyncio_loop(tmp_path):
     assert [d.get("wakes") for d in demux] == [1]
 
 
+def test_batch_span_names_the_positions_of_its_model(tmp_path):
+    """``tm.batch`` carries the served model's patch positions: 1 for a
+    plain TM, 16 for a 3x3 window on 6x6 images."""
+    from repro.core.tm import PatchGeometry
+
+    rng = np.random.default_rng(3)
+    geom = PatchGeometry(6, 6, 3, 3)
+    ctm = encode(TMConfig(3, 8, geom.n_features, patch=geom),
+                 rng.random((3, 8, 2 * geom.n_features)) < 0.1)
+    plain = _model(rng)
+    acc = Accelerator.for_models([plain, ctm])
+    acc.load("plain", acc.compile(plain))
+    acc.load("ctm", acc.compile(ctm))
+    rows = {"plain": rng.integers(0, 2, (2, 16), dtype=np.uint8),
+            "ctm": rng.integers(0, 2, (2, 36), dtype=np.uint8)}
+    for slot, x in rows.items():
+        acc.infer(slot, x)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for slot, x in rows.items():
+            acc.infer(slot, x)
+    finally:
+        jax.profiler.stop_trace()
+    batches = [dict(ev.stats) for ln in _host_events(tmp_path) for ev in ln
+               if ev.name == spans.BATCH]
+    assert [(d.get("slot"), d.get("positions")) for d in batches] == [
+        ("plain", 1), ("ctm", 16)]
+
+
 def test_engine_steps_carry_their_names():
     plan = CapacityPlan(instruction_capacity=256, feature_capacity=16,
                         class_capacity=4, batch_words=1)

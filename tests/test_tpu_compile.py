@@ -8,7 +8,11 @@ they guard every later change at the shapes the chip run serves:
 
 * ``tm_popcount`` (Pallas) at the MNIST capacity point, at the
   ``CapacityPlan()`` default, and with two clause-weight planes;
-* ``tm_popcount_xla`` and the packed train step at the MNIST shape.
+* ``tm_popcount_xla`` and the packed train step at the MNIST shape;
+* the popcount engine's serve step at the Convolutional TM cell's point
+  (200,000 instructions, 136 features a patch, 784-bit images), with one
+  position and with 361: a stream that long reaches the kernel in SMEM
+  blocks, and the patch literals are formed in the same step.
 
 Nothing runs; a compile that passes is not a chip run.  The topology is
 described inside a fixture (never at import: only one process at a time
@@ -111,6 +115,45 @@ def test_tm_popcount_kernel_keeps_its_name_on_v5e(one_chip):
     kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert kernel and all(
         ln.lstrip().startswith("%tm_popcount") for ln in kernel)
+
+
+# the benchmark's Convolutional TM cell (bench/configs/tm_ctm_mnist.json),
+# as CapacityPlan.for_models negotiates it with batch_words=4
+CTM = dict(i_cap=200000, m_cap=10, f_cap=144, words=4, x_cap=784)
+
+
+@pytest.mark.parametrize("positions", [1, 361],
+                         ids=["one-position", "ctm-361"])
+def test_serve_step_compiles_for_v5e_at_the_ctm_point(one_chip, positions):
+    from repro.accel.engines import _popcount_engine_pallas
+
+    i_cap, m_cap, f_cap, words, x_cap = (CTM[k] for k in
+                                         ("i_cap", "m_cap", "f_cap", "words",
+                                          "x_cap"))
+    bi, bw = kernel_blocks(i_cap, words, positions=positions)
+    i_pad = -(-i_cap // bi) * bi
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def serve_step(*args):
+        return _popcount_engine_pallas(
+            *args, weight_planes=1, block_instructions=bi,
+            block_words=bw, interpret=False)
+
+    args = [s((i_pad,), jnp.int32), s((i_pad,), jnp.int32),
+            s((i_pad, m_cap), jnp.uint32), s((i_pad, m_cap), jnp.uint32)]
+    if positions > 1:  # images, and the geometry's patch operands
+        args += [s((32 * words, x_cap), jnp.uint8),
+                 s((f_cap, positions), jnp.int32),
+                 s((positions,), jnp.uint32)]
+    else:  # one position: the patch is the whole row
+        args += [s((32 * words, f_cap), jnp.uint8)]
+    text = jax.jit(serve_step).lower(*args).compile().as_text()
+    kernel = [ln.lstrip() for ln in text.splitlines()
+              if "tpu_custom_call" in ln]
+    # the benchmark finds the kernel's device events by this prefix
+    assert len(kernel) == 1 and kernel[0].startswith("%tm_popcount.1 = ")
 
 
 def test_tm_popcount_xla_compiles_for_v5e_mnist(one_chip):
