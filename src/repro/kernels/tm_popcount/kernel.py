@@ -38,11 +38,17 @@ Block shapes default to ``kernels.tuning.choose_blocks`` (a per-capacity
 synthesis-time choice, never a runtime recompile).
 
 Positions (the Convolutional TM, ``core.tm.PatchGeometry``): packed
-literals ``uint32[L2, P, W]`` carry a position axis.  Each literal row of
-the panel then holds the P x bw words of a word block position-major
-(word ``p * bw + w``, zero-padded to whole 128-lane tiles), so the sweep
-ANDs P x bw words per include, and before step 2 the emit buffer is
-OR-reduced over positions back to ``[bi, bw]`` (a clause fires on a
+literals ``uint32[L2, P, W]`` carry a position axis.  Each literal of the
+panel then holds the P x bw words of a word block position-major (word
+``p * bw + w``) in dense (8, 128) tiles, ``[S, 128]`` with S =
+ceil(P * bw / 128) rounded up to a multiple of 8: row r, lane l holds
+word ``128 r + l``, which is word ``l % bw`` of its position.  Padded
+words are zero.  The sweep ANDs the literal's ``[S, 128]`` tile per
+include and ORs the clause word over its S rows (whole-vreg ORs, then
+sublane rolls) into the one ``[1, 128]`` row it stores in the emit
+buffer, so the buffer is ``[bi, 128]``; before step 2 the lanes a
+multiple of bw apart are ORed together (lane rolls), leaving in lane
+``w < bw`` the OR over positions of word w (a clause fires on a
 datapoint iff it fires at some position).  Padded positions hold zero in
 both polarities, so they never fire.  With 2-D literals (one position)
 the kernel is the plain one, shape for shape.
@@ -154,15 +160,24 @@ def popcount_reduce(
     return sums.transpose(0, 2, 1).reshape(m_cap, w * 32)
 
 
-def _or_positions(emitted: jax.Array, bw: int) -> jax.Array:
-    """``[bi, Q]`` position-major clause words (word ``p * bw + w``, Q a
-    whole number of 128-lane tiles, ``bw`` a power of two up to 128) ->
-    ``[bi, 128]`` whose lane ``w < bw`` holds the OR over positions of
-    word ``w``: the tiles are ORed together, then each lane with the lanes
-    a multiple of ``bw`` away (lane rolls)."""
-    fold = emitted[:, 0:128]
-    for t in range(1, emitted.shape[1] // 128):
-        fold = fold | emitted[:, 128 * t:128 * (t + 1)]
+def _or_rows(words: jax.Array) -> jax.Array:
+    """``[S, 128]`` (S a multiple of 8) -> ``[1, 128]``, the OR of its
+    rows: the (8, 128) tiles are ORed together, then each sublane with
+    the others (sublane rolls)."""
+    fold = words[0:8]
+    for r in range(8, words.shape[0], 8):
+        fold = fold | words[r:r + 8]
+    for shift in (4, 2, 1):
+        fold = fold | pltpu.roll(fold, shift, 0)
+    return fold[0:1]
+
+
+def _or_lanes(emitted: jax.Array, bw: int) -> jax.Array:
+    """``[bi, 128]`` clause words ORed over rows (lane l holds word
+    ``l % bw`` of some positions; ``bw`` a power of two up to 128) -> lane
+    ``w < bw`` holding the OR over positions of word ``w``: each lane is
+    ORed with the lanes a multiple of ``bw`` away (lane rolls)."""
+    fold = emitted
     shift = 64
     while shift >= bw:
         fold = fold | pltpu.roll(fold, shift, 1)
@@ -174,10 +189,11 @@ def _tm_popcount_kernel(
     lit_idx_ref, last_ref,  # SMEM (scalar prefetch): int32[I_pad]
     # (with stream_blocks: one int32[1, 1, bi] block per instruction block)
     mask_pos_ref, mask_neg_ref,  # VMEM uint32[bi, banks], one row per inst
-    lits_ref,  # VMEM uint32[L2, bw] — Feature Memory panel ([L2, Q] with
-    # positions: P x bw words, position-major)
+    lits_ref,  # VMEM uint32[L2, bw] — Feature Memory panel ([L2, S, 128]
+    # with positions: P x bw words, position-major, in (8, 128) tiles)
     out_ref,  # VMEM int32[banks, 32, bw] — the class-sum bank ([.., 128])
-    acc_ref, emit_ref,  # VMEM scratch uint32[1, bw], uint32[bi, bw] (Q)
+    acc_ref, emit_ref,  # VMEM scratch uint32[1, bw], uint32[bi, bw]
+    # (uint32[S, 128], uint32[bi, 128] with positions)
     *,
     positions_bw: int | None = None,  # bw when the words carry positions
     stream_blocks: bool = False,  # the operand vectors come per block
@@ -205,10 +221,20 @@ def _tm_popcount_kernel(
         emit_ref[pl.ds(t, 1), :] = jnp.where(emit, acc, jnp.uint32(0))
         return jnp.where(emit, jnp.uint32(ONES), acc)
 
-    acc_ref[...] = jax.lax.fori_loop(0, bi, body, acc_ref[...])
+    def position_body(t, acc):
+        # one AND of the literal's [S, 128] position words per include; the
+        # clause word is ORed over its rows as it is stored
+        acc = acc & lits_ref[operand(lit_idx_ref, t)]
+        emit = operand(last_ref, t) == 1
+        emit_ref[pl.ds(t, 1), :] = jnp.where(emit, _or_rows(acc),
+                                             jnp.uint32(0))
+        return jnp.where(emit, jnp.uint32(ONES), acc)
+
+    sweep = body if positions_bw is None else position_body
+    acc_ref[...] = jax.lax.fori_loop(0, bi, sweep, acc_ref[...])
     emitted = emit_ref[...]
     if positions_bw is not None:
-        emitted = _or_positions(emitted, positions_bw)
+        emitted = _or_lanes(emitted, positions_bw)
     # one bitplane transpose + popcount reduction per instruction block:
     # row 32c+b of ``planes`` holds, at bit j, datapoint b's output of
     # instruction 32c+j; each mask row selects the same 32 instructions
@@ -366,7 +392,8 @@ def tm_popcount_resident(
     instruction block; each grid step reads a ``(bi, banks)`` row block
     of the masks.  3-D ``packed_lits`` (``[L2, P, W]``) carry positions:
     each word block's P x bw words are laid out position-major in whole
-    128-lane tiles, and the emit buffer is ORed over positions."""
+    (8, 128) tiles, ``[L2, n_blocks * S, 128]``, and each clause word is
+    ORed over positions (rows at the include, lanes once a block)."""
     bi, bw = block_instructions, block_words
     i_pad, banks = mask_pos.shape
     l2, w = packed_lits.shape[0], packed_lits.shape[-1]
@@ -385,17 +412,20 @@ def tm_popcount_resident(
     out_words = bw
     if packed_lits.ndim == 3:
         p = packed_lits.shape[1]
-        q = -(-p * bw // 128) * 128  # a block's words, whole lane tiles
+        rows = -(-p * bw // 1024) * 8  # a block's 128-word rows, whole tiles
         panel = jnp.pad(packed_lits, ((0, 0), (0, 0), (0, w_pad - w)))
         panel = panel.reshape(l2, p, n_blocks, bw).transpose(0, 2, 1, 3)
         panel = jnp.pad(panel.reshape(l2, n_blocks, p * bw),
-                        ((0, 0), (0, 0), (0, q - p * bw)))
-        panel = panel.reshape(l2, n_blocks * q)
+                        ((0, 0), (0, 0), (0, rows * 128 - p * bw)))
+        panel = panel.reshape(l2, n_blocks * rows, 128)
         options["positions_bw"] = bw
-        panel_words, out_words = q, 128
+        out_words = 128
+        lits_spec = pl.BlockSpec((l2, rows, 128), lambda j, i, *_: (0, j, 0))
+        acc_shape = (rows, 128)
     else:
         panel = jnp.pad(packed_lits, ((0, 0), (0, w_pad - w)))
-        panel_words = bw
+        lits_spec = pl.BlockSpec((l2, bw), lambda j, i, *_: (0, j))
+        acc_shape = (1, bw)
 
     kernel = _tm_popcount_kernel
     if options:
@@ -408,15 +438,15 @@ def tm_popcount_resident(
             in_specs=operand_specs + [
                 pl.BlockSpec((bi, banks), lambda j, i, *_: (i, 0)),
                 pl.BlockSpec((bi, banks), lambda j, i, *_: (i, 0)),
-                pl.BlockSpec((l2, panel_words), lambda j, i, *_: (0, j)),
+                lits_spec,
             ],
             out_specs=pl.BlockSpec(
                 (banks, 32, out_words), lambda j, i, *_: (0, 0, j)
             ),
             scratch_shapes=[
                 # packed clause accumulator, block emit buffer
-                pltpu.VMEM((1, panel_words), jnp.uint32),
-                pltpu.VMEM((bi, panel_words), jnp.uint32),
+                pltpu.VMEM(acc_shape, jnp.uint32),
+                pltpu.VMEM((bi, out_words), jnp.uint32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(

@@ -128,20 +128,29 @@ def _naive_sums(lit_idx, last, mask_pos, mask_neg, lits):
     return sums
 
 
-@pytest.mark.parametrize("n_words", [1, 3, 4])
-def test_kernel_position_axis_matches_its_xla_twin(n_words):
+def _random_words(rng, shape, density=0.85):
+    """uint32 words of ``shape[:-1]`` from ``shape[-1] == 32`` bits each
+    set with ``density`` (sparse zeros, so that clause words are not all
+    zero after the ANDs)."""
+    bits = (rng.random(shape) < density).astype(np.uint32)
+    return (bits << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint32)
+
+
+@pytest.mark.parametrize("n_words,p", [(1, 7), (3, 7), (4, 7), (4, 300)],
+                         ids=["1", "3", "4", "4-300"])
+def test_kernel_position_axis_matches_its_xla_twin(n_words, p):
     """The Pallas kernel (interpret mode) with a position axis equals the
     XLA twin, across word counts the OR over positions pads to a power of
-    two, and with one position equals the plain kernel."""
-    rng = np.random.default_rng(n_words)
-    i_cap, m_cap, l2, p = 96, 3, 20, 7
+    two, with a literal's P x bw words inside one 128-lane row (7 x 4) or
+    over more than one (8, 128) tile (300 x 4 = 1,200 words, 16 rows),
+    and with one position equals the plain kernel."""
+    rng = np.random.default_rng(n_words + p)
+    i_cap, m_cap, l2 = 96, 3, 20
     lit_idx = rng.integers(0, l2, i_cap).astype(np.int32)
     last = (rng.random(i_cap) < 0.3).astype(np.int32)
     mask_pos = rng.integers(0, 2**32, (m_cap, 3), dtype=np.uint32)
     mask_neg = rng.integers(0, 2**32, (m_cap, 3), dtype=np.uint32) & ~mask_pos
-    # sparse words, so that clause words are not all zero after the ANDs
-    lits = (rng.random((l2, p, n_words, 32)) < 0.85).astype(np.uint32)
-    lits = (lits << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint32)
+    lits = _random_words(rng, (l2, p, n_words, 32))
     args = [jnp.asarray(a) for a in (lit_idx, last, mask_pos, mask_neg)]
     want = np.asarray(tm_popcount_xla(*args, jnp.asarray(lits)))
     got = np.asarray(tm_popcount(*args, jnp.asarray(lits), interpret=True))
@@ -154,11 +163,36 @@ def test_kernel_position_axis_matches_its_xla_twin(n_words):
         *args, jnp.asarray(lits[:, :1]), interpret=True)))
 
 
-@pytest.mark.parametrize("positions", [0, 5])
+@pytest.mark.parametrize("fill", [0xFFFFFFFF, 0], ids=["ones", "zeros"])
+def test_kernel_padded_position_words_never_fire(fill):
+    """Every real position word the same, all ones or all zero, at 300
+    positions of 3 words (bw 4: 1,200 words in a literal's 16 rows, so
+    padded words, lanes and rows): with ones every clause fires on every
+    datapoint, and with zeros none does; either way the kernel equals the
+    XLA twin and the one-instruction-at-a-time contract."""
+    rng = np.random.default_rng(fill & 7)
+    i_cap, m_cap, l2, p, n_words = 96, 3, 20, 300, 3
+    lit_idx = rng.integers(0, l2, i_cap).astype(np.int32)
+    last = (rng.random(i_cap) < 0.3).astype(np.int32)
+    mask_pos = rng.integers(0, 2**32, (m_cap, 3), dtype=np.uint32)
+    mask_neg = rng.integers(0, 2**32, (m_cap, 3), dtype=np.uint32) & ~mask_pos
+    lits = np.full((l2, p, n_words), fill, np.uint32)
+    want = _naive_sums(lit_idx, last, mask_pos, mask_neg, lits)
+    assert (want[:, :1] == want).all()  # the same for every datapoint
+    assert want.any() == bool(fill)
+    args = [jnp.asarray(a) for a in (lit_idx, last, mask_pos, mask_neg,
+                                     lits)]
+    assert np.array_equal(np.asarray(tm_popcount_xla(*args)), want)
+    assert np.array_equal(np.asarray(tm_popcount(*args, interpret=True)),
+                          want)
+
+
+@pytest.mark.parametrize("positions", [0, 5, 600])
 def test_kernel_streams_a_long_program_in_smem_blocks(monkeypatch, positions):
     """A stream longer than ``PREFETCH_INSTRUCTIONS`` reaches the kernel
-    one SMEM block per instruction block, with the same sums.  (Shapes of
-    their own: the kernel reads the bound when it is traced.)"""
+    one SMEM block per instruction block, with the same sums, with and
+    without positions (600 x 2 words: more than one (8, 128) tile).
+    (Shapes of their own: the kernel reads the bound when it is traced.)"""
     from repro.kernels.tm_popcount import kernel
 
     monkeypatch.setattr(kernel, "PREFETCH_INSTRUCTIONS", 64)
@@ -168,9 +202,7 @@ def test_kernel_streams_a_long_program_in_smem_blocks(monkeypatch, positions):
     last = (rng.random(i_cap) < 0.3).astype(np.int32)
     mask_pos = rng.integers(0, 2**32, (m_cap, 5), dtype=np.uint32)
     mask_neg = rng.integers(0, 2**32, (m_cap, 5), dtype=np.uint32) & ~mask_pos
-    shape = (l2, positions, 2, 32) if positions else (l2, 1, 2, 32)
-    lits = (rng.random(shape) < 0.85).astype(np.uint32)
-    lits = (lits << np.arange(32, dtype=np.uint32)).sum(-1, dtype=np.uint32)
+    lits = _random_words(rng, (l2, positions or 1, 2, 32))
     args = [jnp.asarray(a) for a in (lit_idx, last, mask_pos, mask_neg)]
     panel = jnp.asarray(lits if positions else lits[:, 0])
     got = np.asarray(tm_popcount(*args, panel, block_instructions=64,

@@ -12,7 +12,8 @@ they guard every later change at the shapes the chip run serves:
 * the popcount engine's serve step at the Convolutional TM cell's point
   (200,000 instructions, 136 features a patch, 784-bit images), with one
   position and with 361: a stream that long reaches the kernel in SMEM
-  blocks, and the patch literals are formed in the same step.
+  blocks, the patch literals are formed in the same step, and the
+  kernel's scoped VMEM shows the ``[bi, 128]`` emit buffer.
 
 Nothing runs; a compile that passes is not a chip run.  The topology is
 described inside a fixture (never at import: only one process at a time
@@ -20,6 +21,8 @@ may load the TPU library), and the persistent compilation cache is off
 around these compiles, since an entry written for a described chip cannot
 be read back without one.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -154,6 +157,13 @@ def test_serve_step_compiles_for_v5e_at_the_ctm_point(one_chip, positions):
               if "tpu_custom_call" in ln]
     # the benchmark finds the kernel's device events by this prefix
     assert len(kernel) == 1 and kernel[0].startswith("%tm_popcount.1 = ")
+    if positions > 1:
+        # the emit buffer holds one 128-lane row an instruction, so the
+        # kernel's scoped VMEM stays under what a row of all 361 x 4
+        # position words (12 lane tiles) would take for the buffer alone
+        scoped = re.search(r'"used_scoped_memory_configs":\[\{[^}]*'
+                           r'"size":"(\d+)"', kernel[0])
+        assert scoped and int(scoped.group(1)) < bi * 12 * 128 * 4
 
 
 def test_tm_popcount_xla_compiles_for_v5e_mnist(one_chip):
